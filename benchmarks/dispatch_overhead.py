@@ -549,4 +549,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.device import enable_compile_cache
+    enable_compile_cache()
     main()

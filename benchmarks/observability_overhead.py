@@ -217,4 +217,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.device import enable_compile_cache
+    enable_compile_cache()
     main()

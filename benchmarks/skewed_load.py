@@ -371,4 +371,6 @@ def summary(quick: bool = False) -> Dict[str, float]:
 
 
 if __name__ == "__main__":
+    from repro.core.device import enable_compile_cache
+    enable_compile_cache()
     main()

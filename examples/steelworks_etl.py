@@ -222,4 +222,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.core.device import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -50,7 +50,7 @@ Three registered implementations:
   ``numpy``   pure-host reference (no jit, no device) — the oracle,
   ``jax``     jitted jnp (XLA; CPU/GPU/TPU via jax.default_backend),
   ``pallas``  TPU Pallas kernels (``hash_join`` / ``segment_kpi``),
-              interpret-mode on CPU.
+              compiled on TPU, interpret-mode on CPU.
 
 Selection order: explicit name > ``ETLConfig.backend`` > the
 ``DODETL_BACKEND`` environment variable > ``"jax"``. A fourth backend is a
@@ -948,13 +948,18 @@ class JaxBackend(ComputeBackend):
                               n_shards):
         # mesh path: ONE shard_map dispatch per row block — every device
         # folds the (replicated) block against its own ownership mask,
-        # device-local, no collectives. Falls back to the host reference
-        # (one masked fold_segments per shard) when no matching mesh is
-        # attached, so callers never branch.
+        # device-local, no collectives. Without a mesh the host reference
+        # (one masked fold_segments per shard) computes the same stack; a
+        # mesh of the wrong size is a deployment error, never a silent
+        # fallback to the host.
         mesh = self.mesh
-        if mesh is None or mesh.devices.size != n_shards:
+        if mesh is None:
             return super().fold_segments_sharded(
                 seg_ids, values, n_segments, owners, n_shards)
+        if mesh.devices.size != n_shards:
+            raise ValueError(
+                f"attached mesh has {mesh.devices.size} devices but the "
+                f"serving plane has {n_shards} shards")
         import jax.numpy as jnp
         seg = np.asarray(seg_ids, np.int64)
         vals = np.asarray(values, np.float32)
@@ -1001,7 +1006,6 @@ def _make_mesh_fold(mesh):
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]
@@ -1031,9 +1035,9 @@ def _make_mesh_fold(mesh):
             table = jnp.concatenate(
                 [cnt[0][:, None], sums[0], mins[0], maxs[0]], axis=1)
             return table[None]          # [1, S, W] -> stacked [K, S, W]
-        return shard_map(device_fold, mesh,
-                         in_specs=(P(), P(), P()),
-                         out_specs=P(axis))(seg, vals, owners)
+        return jax.shard_map(device_fold, mesh=mesh,
+                             in_specs=(P(), P(), P()),
+                             out_specs=P(axis))(seg, vals, owners)
 
     return fold
 
@@ -1197,9 +1201,11 @@ def _prefix_fold_jnp(table):
 # ========================================================== pallas backend
 @register_backend("pallas")
 class PallasBackend(ComputeBackend):
-    """TPU Pallas kernels (``hash_join`` one-hot-MXU probe, ``segment_kpi``
-    fused KPI + rollup). On CPU hosts the kernels run in interpret mode —
-    slow but contract-identical, so parity tests cover the kernel path.
+    """TPU Pallas kernels (``hash_join`` slot-tiled linear probe,
+    ``segment_kpi`` fused KPI + rollup). Compiled on a TPU; on the CPU the
+    kernels run in interpret mode (``repro.core.device.pallas_interpret``)
+    — slow but contract-identical, so parity tests cover the kernel
+    path.
 
     ``transform_block`` issues a constant FEW dispatch groups (two probes,
     the optional hop probe, the fused KPI kernel) rather than jax's single

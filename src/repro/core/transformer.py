@@ -104,30 +104,7 @@ def transform_kernel(prod: jax.Array,
                            q_keys, q_vals, q_txn, join_depth)
 
 
-def _transform_rollup(prod: jax.Array,
-                      eq_keys: jax.Array, eq_vals: jax.Array,
-                      eq_txn: jax.Array,
-                      q_keys: jax.Array, q_vals: jax.Array,
-                      q_txn: jax.Array,
-                      join_depth: int = 1, n_units: int = 1
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    facts, found = _transform_math(prod, eq_keys, eq_vals, eq_txn,
-                                   q_keys, q_vals, q_txn, join_depth)
-    unit = facts[:, 0].astype(jnp.int32)
-    ok = found & (unit >= 0) & (unit < n_units)
-    kpis = jnp.concatenate(
-        [facts[:, 3:7], jnp.ones((facts.shape[0], 1), jnp.float32)],
-        axis=-1)
-    kpis = jnp.where(ok[:, None], kpis, 0.0)
-    # rows failing the guard route to a trash segment past n_units
-    agg = jax.ops.segment_sum(kpis, jnp.where(ok, unit, n_units),
-                              num_segments=n_units + 1)[:n_units]
-    return facts, found, agg
-
-
-_ROLLUP_KERNEL_JIT = None
-
-
+@functools.partial(jax.jit, static_argnames=("join_depth", "n_units"))
 def transform_rollup_kernel(prod: jax.Array,
                             eq_keys: jax.Array, eq_vals: jax.Array,
                             eq_txn: jax.Array,
@@ -141,21 +118,20 @@ def transform_rollup_kernel(prod: jax.Array,
     block's valid facts (pad rows carry unit -1 and drop out of the
     in-range guard, exactly like out-of-range units).
 
-    Jitted lazily on first call: the padded production buffer is DONATED
-    on real accelerators (a per-dispatch temporary, uploaded fresh each
-    call, so XLA reuses its memory for the outputs) — but deciding that
-    needs ``jax.default_backend()``, which initializes the platform, and
-    an import-time call would lock the platform before callers can set
-    XLA flags (CPU also warns on every donating compile)."""
-    global _ROLLUP_KERNEL_JIT
-    if _ROLLUP_KERNEL_JIT is None:
-        donate = () if jax.default_backend() == "cpu" else (0,)
-        _ROLLUP_KERNEL_JIT = functools.partial(
-            jax.jit, static_argnames=("join_depth", "n_units"),
-            donate_argnums=donate)(_transform_rollup)
-    return _ROLLUP_KERNEL_JIT(prod, eq_keys, eq_vals, eq_txn,
-                              q_keys, q_vals, q_txn,
-                              join_depth=join_depth, n_units=n_units)
+    Nothing is donated: no output has the production block's [n, 8]
+    shape, so XLA could never reuse its buffer."""
+    facts, found = _transform_math(prod, eq_keys, eq_vals, eq_txn,
+                                   q_keys, q_vals, q_txn, join_depth)
+    unit = facts[:, 0].astype(jnp.int32)
+    ok = found & (unit >= 0) & (unit < n_units)
+    kpis = jnp.concatenate(
+        [facts[:, 3:7], jnp.ones((facts.shape[0], 1), jnp.float32)],
+        axis=-1)
+    kpis = jnp.where(ok[:, None], kpis, 0.0)
+    # rows failing the guard route to a trash segment past n_units
+    agg = jax.ops.segment_sum(kpis, jnp.where(ok, unit, n_units),
+                              num_segments=n_units + 1)[:n_units]
+    return facts, found, agg
 
 
 def q_vals_cols(q_rows: jax.Array) -> Tuple[jax.Array, jax.Array]:

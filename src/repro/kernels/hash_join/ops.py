@@ -2,9 +2,9 @@
 (repro.core.backend.PallasBackend)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.core.device import pallas_interpret
 from repro.kernels.hash_join.hash_join import hash_join_kernel
 
 
@@ -14,10 +14,9 @@ def hash_join(query_keys, keys_tbl, vals_tbl, txn_tbl, block_q: int = 256):
     if pad:
         query_keys = jnp.concatenate(
             [query_keys, jnp.full((pad,), -2, query_keys.dtype)])
-    on_tpu = jax.default_backend() == "tpu"
     vals, found, txn = hash_join_kernel(
         query_keys, keys_tbl, vals_tbl, txn_tbl, block_q=block_q,
-        interpret=not on_tpu)
+        interpret=pallas_interpret())
     if pad:
         vals, found, txn = vals[:n], found[:n], txn[:n]
     return vals, found, txn

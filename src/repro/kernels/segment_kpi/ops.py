@@ -11,9 +11,9 @@ the hot path never re-uploads the block for a separate rollup dispatch.
 segment count — see ``repro.core.backend._fold_blocks``)."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.core.device import pallas_interpret
 from repro.kernels.segment_kpi.segment_kpi import (fold_segments_kernel,
                                                    gather_stats_kernel,
                                                    segment_kpi_kernel,
@@ -34,9 +34,8 @@ def segment_kpi(prod, eq_rows, q_rows, *, n_units: int = 32,
         prod = jnp.concatenate([prod, padrow])
         eq_rows = jnp.concatenate([eq_rows, padrow])
         q_rows = jnp.concatenate([q_rows, padrow])
-    on_tpu = jax.default_backend() == "tpu"
     facts, agg = segment_kpi_kernel(prod, eq_rows, q_rows, n_units=n_units,
-                                    block=block, interpret=not on_tpu)
+                                    block=block, interpret=pallas_interpret())
     return facts[:n], agg.sum(axis=0)
 
 
@@ -47,9 +46,8 @@ def segment_rollup(facts, *, n_units: int = 32, block: int = 256):
     if pad:
         facts = jnp.concatenate(
             [facts, jnp.zeros((pad, facts.shape[1]), jnp.float32)])
-    on_tpu = jax.default_backend() == "tpu"
     agg = segment_rollup_kernel(facts, n_units=n_units, block=block,
-                                interpret=not on_tpu)
+                                interpret=pallas_interpret())
     return agg.sum(axis=0)
 
 
@@ -65,9 +63,8 @@ def fold_segments(packed, *, n_segments: int = 32, block: int = 256):
             [jnp.full((pad, 1), -1.0, jnp.float32),
              jnp.zeros((pad, L), jnp.float32)], axis=1)
         packed = jnp.concatenate([packed, padrow])
-    on_tpu = jax.default_backend() == "tpu"
     agg = fold_segments_kernel(packed, n_segments=n_segments, block=block,
-                               interpret=not on_tpu)     # [nb, S, 1+3L]
+                               interpret=pallas_interpret())  # [nb, S, 1+3L]
     return jnp.concatenate(
         [agg[:, :, :1 + L].sum(axis=0),
          agg[:, :, 1 + L:1 + 2 * L].min(axis=0),
@@ -84,9 +81,8 @@ def gather_stats(table, idx, *, block: int = 256):
     pad = (-n) % block
     if pad:
         idx = jnp.concatenate([idx, jnp.zeros((pad, 1), jnp.float32)])
-    on_tpu = jax.default_backend() == "tpu"
     out = gather_stats_kernel(table, idx, block=block,
-                              interpret=not on_tpu)
+                              interpret=pallas_interpret())
     return out[:n]
 
 

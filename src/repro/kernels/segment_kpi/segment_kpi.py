@@ -19,6 +19,10 @@ from jax.experimental import pallas as pl
 
 EPS = 1e-6
 N_FACT = 10
+# one-hot matmuls select or sum f32 values. At default precision the TPU
+# may multiply f32 operands in reduced (bf16) passes, so every dot asks for
+# full f32 precision: a one-hot selection of finite values is then exact
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _kpi_kernel(prod_ref, eq_ref, q_ref, facts_ref, agg_ref, *,
@@ -60,7 +64,7 @@ def _kpi_kernel(prod_ref, eq_ref, q_ref, facts_ref, agg_ref, *,
     kpis = jnp.stack([availability, performance, quality, oee,
                       jnp.ones_like(oee)], axis=-1)      # [B, 5]
     agg_ref[0] = jax.lax.dot_general(
-        onehot, kpis, (((0,), (0,)), ((), ())),
+        onehot, kpis, (((0,), (0,)), ((), ())), precision=_EXACT,
         preferred_element_type=jnp.float32)              # [n_units, 5]
 
 
@@ -73,7 +77,7 @@ def _rollup_kernel(facts_ref, agg_ref, *, n_units: int, block: int):
     kpis = jnp.concatenate(
         [facts[:, 3:7], jnp.ones((block, 1), jnp.float32)], axis=-1)
     agg_ref[0] = jax.lax.dot_general(
-        onehot, kpis, (((0,), (0,)), ((), ())),
+        onehot, kpis, (((0,), (0,)), ((), ())), precision=_EXACT,
         preferred_element_type=jnp.float32)               # [n_units, 5]
 
 
@@ -97,7 +101,8 @@ def _fold_kernel(packed_ref, out_ref, *, n_segments: int, n_lanes: int,
     ones = jnp.ones((block, 1), jnp.float32)
     cnt_sums = jax.lax.dot_general(                       # [S, 1+L]
         onehot, jnp.concatenate([ones, vals], axis=-1),
-        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (((0,), (0,)), ((), ())), precision=_EXACT,
+        preferred_element_type=jnp.float32)
 
     mins = []
     maxs = []
@@ -129,7 +134,8 @@ def _gather_kernel(table_ref, idx_ref, out_ref, *, n_segments: int,
     onehot = hit.astype(jnp.float32)
     cnt_sums = jax.lax.dot_general(                       # [B, 1+L]
         onehot, table[:, :1 + L],
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (((1,), (0,)), ((), ())), precision=_EXACT,
+        preferred_element_type=jnp.float32)
 
     mins = []
     maxs = []
@@ -150,7 +156,7 @@ def _gather_kernel(table_ref, idx_ref, out_ref, *, n_segments: int,
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def gather_stats_kernel(table: jax.Array, idx: jax.Array, *,
-                        block: int = 256, interpret: bool = True):
+                        block: int = 256, interpret: bool):
     """table [S, 1+3L] packed fold stats; idx [N, 1] f32 segment ids
     (exact below 2^24), N % block == 0, every id in [0, S). Returns
     [N, 1+4L]: [count | sums | mins | maxs | means] per query row."""
@@ -178,7 +184,7 @@ def gather_stats_kernel(table: jax.Array, idx: jax.Array, *,
 @functools.partial(jax.jit,
                    static_argnames=("n_segments", "block", "interpret"))
 def fold_segments_kernel(packed: jax.Array, *, n_segments: int = 32,
-                         block: int = 256, interpret: bool = True):
+                         block: int = 256, interpret: bool):
     """packed [N, 1+L] f32 (seg id lane + L value lanes), N % block == 0.
     Returns [blocks, n_segments, 1+3L]: per-block packed fold tables —
     caller combines across blocks (count/sum add, min min, max max)."""
@@ -203,7 +209,7 @@ def fold_segments_kernel(packed: jax.Array, *, n_segments: int = 32,
 
 @functools.partial(jax.jit, static_argnames=("n_units", "block", "interpret"))
 def segment_rollup_kernel(facts: jax.Array, *, n_units: int = 32,
-                          block: int = 256, interpret: bool = True):
+                          block: int = 256, interpret: bool):
     """Standalone per-unit KPI rollup over already-built fact rows
     [N, N_FACT] f32 (col 0 = unit, col 9 = valid flag): one-hot matmul on
     the MXU, same discipline as the fused ``segment_kpi_kernel`` epilogue.
@@ -225,7 +231,7 @@ def segment_rollup_kernel(facts: jax.Array, *, n_units: int = 32,
 @functools.partial(jax.jit, static_argnames=("n_units", "block", "interpret"))
 def segment_kpi_kernel(prod: jax.Array, eq_rows: jax.Array,
                        q_rows: jax.Array, *, n_units: int = 32,
-                       block: int = 256, interpret: bool = True):
+                       block: int = 256, interpret: bool):
     """prod/eq_rows/q_rows: [N, 8] f32 (production payloads + joined master
     rows; a row with col1 < 0 marks a join miss). Returns (facts [N, 10],
     agg [blocks, n_units, 5]) — caller sums agg over blocks."""

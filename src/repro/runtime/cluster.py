@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.configs.dod_etl import ETLConfig
 from repro.core.cdc import ChangeLog, SourceDatabase
+from repro.core.device import DEVICE_ERRORS
 from repro.core.metrics import LatencyRecorder, percentiles_ms
 from repro.core.pipeline import DODETLPipeline, StreamProcessorWorker
 from repro.core.records import RecordBatch
@@ -253,6 +254,9 @@ class WorkerRuntime:
         self.hb: Dict[str, float] = {}
         self.started_at: Optional[float] = None
         self._threads: List[threading.Thread] = []
+        # what killed a stage thread, other than a drill's InjectedCrash:
+        # a device/compiler failure or a bug. run_until_idle re-raises it
+        self.error: Optional[BaseException] = None
         # observability: spans go to the pipeline's tracer (NULL_TRACER by
         # default — zero-overhead seam); the runtime shares the worker's
         # metrics shard, registers its freshness reservoir there (one read
@@ -296,10 +300,11 @@ class WorkerRuntime:
         self.started_at = time.perf_counter()
         for stage in ("ingest", "transform", "load"):
             self.hb[stage] = self.started_at
-        for fn, tag in ((self._ingest_loop, "ingest"),
-                        (self._transform_loop, "transform"),
-                        (self._load_loop, "load")):
-            t = threading.Thread(target=fn, daemon=True,
+        for body, tag in ((self._ingest_body, "ingest"),
+                          (self._transform_body, "transform"),
+                          (self._load_body, "load")):
+            t = threading.Thread(target=self._run_stage, args=(body,),
+                                 daemon=True,
                                  name=f"{self.worker.name}.{tag}")
             t.start()
             self._threads.append(t)
@@ -329,6 +334,23 @@ class WorkerRuntime:
         return wedged
 
     # ---------------------------------------------------------- stage plumbing
+    def _run_stage(self, body) -> None:
+        # InjectedCrash (a BaseException) kills just this stage thread —
+        # the in-process analogue of the node dying mid-stage; the drill
+        # waits on fault.tripped and abandons the cluster. Anything else
+        # that escapes a live worker's stage is recorded for
+        # run_until_idle to re-raise (and still printed by the thread's
+        # excepthook); a zombie of an evicted worker failing on its fenced
+        # consumer group is expected and not recorded.
+        try:
+            body()
+        except InjectedCrash:
+            return
+        except BaseException as e:
+            if not self.dead:
+                self.error = e
+            raise
+
     def _put(self, q: "queue_mod.Queue", item) -> bool:
         while not self.stop.is_set():
             try:
@@ -400,15 +422,6 @@ class WorkerRuntime:
         return (self.pipe.cfg.buffer_capacity - len(self.worker.buffer)
                 - in_flight - self.retry_inflight)
 
-    def _ingest_loop(self) -> None:
-        # InjectedCrash (a BaseException) kills just this stage thread —
-        # the in-process analogue of the node dying mid-stage; the drill
-        # waits on fault.tripped and abandons the cluster
-        try:
-            self._ingest_body()
-        except InjectedCrash:
-            return
-
     def _ingest_body(self) -> None:
         pipe, w = self.pipe, self.worker
         while not self.stop.is_set():
@@ -463,12 +476,6 @@ class WorkerRuntime:
                 time.sleep(pipe.cfg.idle_backoff_s)
 
     # -------------------------------------------------------- stage: transform
-    def _transform_loop(self) -> None:
-        try:
-            self._transform_body()
-        except InjectedCrash:
-            return
-
     def _transform_body(self) -> None:
         device = self.worker.backend.device
         while True:
@@ -503,18 +510,21 @@ class WorkerRuntime:
         enqueued asynchronously behind the compute.
 
         Poison handling: a transform that raises a plain ``Exception``
-        (never ``InjectedCrash`` — drills must still kill the thread) is
-        re-probed by bisection to isolate the records that
+        is re-probed by bisection to isolate the records that
         deterministically fail. Good records keep their original order
         and proceed; poison records ride the hand-off in ``dead`` and
         are quarantined (offsets still committed) by the load stage —
-        the worker never crash-loops on a bad record. Returns
+        the worker never crash-loops on a bad record. Errors of the
+        device, its runtime or its compiler (``DEVICE_ERRORS``) are no
+        record's fault and would quarantine every record alike, so they
+        propagate and fail the run, as ``InjectedCrash`` drills still
+        kill the thread. Returns
         ``(good_batch, block_or_None, dead_batch_or_None)``."""
         tf = self.worker.transformer
         try:
             return batch, tf.transform_block(batch, eq, qu
                                              ).start_host_copy(), None
-        except InjectedCrash:
+        except (InjectedCrash, *DEVICE_ERRORS):
             raise
         except Exception:
             pass
@@ -526,7 +536,7 @@ class WorkerRuntime:
             try:
                 tf.transform_block(batch.take(idx), eq, qu)   # probe
                 good_idx.append(idx)
-            except InjectedCrash:
+            except (InjectedCrash, *DEVICE_ERRORS):
                 raise
             except Exception:
                 if len(idx) == 1:
@@ -601,12 +611,6 @@ class WorkerRuntime:
                 if block is not None:
                     self._load_and_record(good, block)
             self.retry_inflight = 0
-
-    def _load_loop(self) -> None:
-        try:
-            self._load_body()
-        except InjectedCrash:
-            return
 
     def _load_body(self) -> None:
         while True:
@@ -931,11 +935,16 @@ class ConcurrentCluster:
         empty late buffers) or no progress has been made for ``stall_s``
         (e.g. buffered records whose master data never arrives — the
         paper's watermark semantics say those WAIT, so a stall is a clean
-        exit, not an error). Returns total records loaded."""
+        exit, not an error). Returns total records loaded. Re-raises the
+        error that killed a worker stage thread (a device or compiler
+        failure, or a bug): such a run fails instead of idling."""
         t0 = time.perf_counter()
         last = (-1, -1)
         last_change = t0
         while time.perf_counter() - t0 < timeout:
+            for rt in list(self.runtimes.values()):
+                if rt.error is not None:
+                    raise rt.error
             buffered = self._idle_buffered()
             state = (self.records_done(), buffered)
             if state != last:

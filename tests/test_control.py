@@ -304,14 +304,15 @@ class _PoisonError(Exception):
     pass
 
 
-def _poison_transform(worker, key):
-    """Wrap a worker's transform so any batch containing ``key`` raises a
-    plain Exception — a deterministic poison record, not a drill kill."""
+def _poison_transform(worker, key, error=_PoisonError):
+    """Wrap a worker's transform so any batch containing ``key`` raises
+    ``error`` — by default a plain Exception: a deterministic poison
+    record, not a drill kill."""
     orig = worker.transformer.transform_block
 
     def wrapped(batch, eq, qu):
         if np.any(batch.business_key == key):
-            raise _PoisonError(f"poison key {key}")
+            raise error(f"poison key {key}")
         return orig(batch, eq, qu)
 
     worker.transformer.transform_block = wrapped
@@ -356,6 +357,34 @@ def test_poison_records_quarantined_not_crash_looped():
     assert h["counters"]["worker.dead_lettered"] == quarantined
     per_worker = sum(w["dead_lettered"] for w in h["workers"].values())
     assert per_worker == quarantined
+
+
+@pytest.mark.filterwarnings(              # the dying stage thread's traceback
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+def test_device_error_fails_the_run_not_the_records():
+    """A transform that fails in the device, its runtime or its compiler
+    (``JaxRuntimeError``) is no record's fault: bisecting it would
+    quarantine every record alike and report a clean run. It must
+    propagate out of ``run_until_idle`` and dead-letter nothing."""
+    import jax
+
+    n, bad_key = 2500, 3
+    _, _, pipe = build(2, n, late_frac=0.0)
+    for w in pipe.workers:
+        _poison_transform(w, bad_key, error=jax.errors.JaxRuntimeError)
+    pipe.extract()
+    cluster = ConcurrentCluster(pipe, poll_cdc=False)
+    cluster.start()
+    try:
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match=f"poison key {bad_key}"):
+            cluster.run_until_idle(timeout=60)
+    finally:
+        cluster.stop_all()
+    assert sum(len(rt.worker.dead_letter)
+               for rt in cluster.runtimes.values()) == 0
+    assert cluster.health()["counters"].get("worker.dead_lettered", 0) == 0
+    assert pipe.warehouse.rows_loaded < n      # the failing batch never loads
 
 
 def test_dead_letter_export_restore_roundtrip():

@@ -89,11 +89,53 @@ def test_hash_join_sweep(n_slots, n_keys, n_queries):
          rng.integers(2 * 10**6, 3 * 10**6, n_queries - n_queries // 2)]),
         jnp.int32)
     kt, vt, tt = tbl.device_state()
-    v1, f1, t1 = hash_join(queries, kt, vt, tt)
-    v2, f2, t2 = hash_join_ref(queries, kt, vt, tt)
-    assert bool((f1 == f2).all())
-    np.testing.assert_allclose(np.asarray(v1), np.asarray(v2))
-    assert float(jnp.asarray(f1, jnp.float32).mean()) >= 0.49
+    got = hash_join(queries, kt, vt, tt)
+    _assert_bitwise(got, hash_join_ref(queries, kt, vt, tt))
+    assert float(jnp.asarray(got[1], jnp.float32).mean()) >= 0.49
+
+
+def _assert_bitwise(got, want):
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+
+
+def _keys_hashing_to(slot, n_slots, count, rng):
+    from repro.core.cache import hash32_np
+    cand = rng.integers(0, 2**31 - 1, 400_000)
+    return cand[hash32_np(cand) % n_slots == slot][:count]
+
+
+@pytest.mark.parametrize("n_slots,n_keys", [
+    (1024, 250), (1280, 150), (4096, 1000), (1536, 300)])
+def test_hash_join_slot_tiles(n_slots, n_keys):
+    """Tables spanning several slot tiles, with probe chains planted
+    across a tile edge and across the wrap from the last slot to slot 0:
+    the tiled probe is bit-identical to the sequential oracle."""
+    from repro.core.cache import InMemoryTable
+    from repro.kernels.hash_join.hash_join import SLOT_TILE
+    rng = np.random.default_rng(n_slots)
+    edge = _keys_hashing_to(SLOT_TILE - 2, n_slots, 6, rng)
+    wrap = _keys_hashing_to(n_slots - 2, n_slots, 6, rng)
+    other = rng.choice(10**6, n_keys, replace=False) + 2**30
+    tbl = InMemoryTable(n_slots)
+    for keys in (np.concatenate([edge, wrap]), other):   # chains first
+        tbl.upsert(keys, rng.normal(size=(len(keys), 8)).astype(np.float32),
+                   rng.integers(0, 10**6, len(keys)))
+    assert tbl.n_slots == n_slots               # no rehash: chains as planted
+    slot_of = {int(k): int(np.nonzero(tbl.keys == k)[0][0])
+               for k in np.concatenate([edge, wrap])}
+    assert max(slot_of[int(k)] for k in edge) >= SLOT_TILE    # crosses edge
+    assert min(slot_of[int(k)] for k in wrap) < 8             # wraps to 0
+    absent = rng.integers(2 * 10**9, 2**31 - 1, 400)
+    queries = jnp.asarray(np.concatenate(
+        [edge, wrap, rng.choice(other, 400), absent,
+         _keys_hashing_to(n_slots - 1, n_slots, 4, rng)])[:768], jnp.int32)
+    kt, vt, tt = tbl.device_state()
+    got = hash_join(queries, kt, vt, tt)
+    _assert_bitwise(got, hash_join_ref(queries, kt, vt, tt))
+    assert bool(np.asarray(got[1])[:len(edge) + len(wrap)].all())
 
 
 def test_segment_kpi_sweep():
