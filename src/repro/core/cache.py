@@ -73,6 +73,8 @@ class InMemoryTable:
         self.version = 0             # bumped on every mutation
         self._snap = None            # memoized CacheSnapshot
         self._snap_version = -1
+        self.upload_bytes = 0        # host->device bytes of the last
+                                     # snapshot_view (0 when memoized)
 
     # ------------------------------------------------------------ updates
     def _slot_of(self, key: int) -> int:
@@ -287,11 +289,16 @@ class InMemoryTable:
         device backends it pins the (immutable) device mirror; for host
         backends it copies the arrays. Memoized per `version`, so in steady
         state (master data changes rarely — the paper's premise) it is a
-        few attribute reads."""
+        few attribute reads. ``upload_bytes`` says what the call
+        re-uploaded to the device."""
+        self.upload_bytes = 0
         if self._snap is None or self._snap_version != (self.version,
                                                         device):
             if device:
+                before = self._device or (None, None, None)
                 state = self.device_state()
+                self.upload_bytes = sum(
+                    a.nbytes for a, b in zip(state, before) if a is not b)
                 self._snap = CacheSnapshot(None, None, None, self.watermark,
                                            state, backend=self._backend)
             else:

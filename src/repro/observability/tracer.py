@@ -1,24 +1,49 @@
 """Stage-span tracer: per-batch spans over the pipeline's stage seams,
-exportable as Chrome-trace/Perfetto JSON.
+exportable as Chrome-trace/Perfetto JSON and, while a ``jax.profiler``
+trace runs, written into it as host annotations.
 
 The enable/disable seam copies ``durability.faults``'s ``NULL_INJECTOR``
 pattern exactly: every instrumented component holds a ``tracer``
 attribute defaulting to the module singleton ``NULL_TRACER``, whose
-``span()`` returns one shared, stateless no-op context manager — the
-disabled hot path costs two attribute lookups and a call, allocates
-NOTHING persistent, and needs no ``if tracing:`` branches at the call
-sites. Swap in a ``StageTracer`` and the same call sites emit real
-spans.
+``span()`` returns one shared, stateless no-op context manager and whose
+``record()`` does nothing — the disabled hot path costs two attribute
+lookups and a call, allocates NOTHING persistent, and needs no
+``if tracing:`` branches at the call sites. Swap in a ``StageTracer``
+and the same call sites emit real spans.
 
-Span seams (the six stage boundaries plus repartition phases):
+Span seams and their arguments. Every span made by ``span()`` also
+carries ``cpu_s``, the thread CPU time over the span (wall time minus
+``cpu_s`` is time the thread waited: a lock, the interpreter lock, the
+device, a queue). ``batch`` is the worker's fetch ordinal, so
+``(worker, batch)`` follows one fetched batch from stage to stage;
+the retry path, whose records come from many fetches, carries -1.
+Indented seams nest inside the one above them on the same thread.
 
-    ingest.fetch        broker poll -> hand-off      (per worker, per poll)
-    transform.dispatch  device transform dispatch    (per batch)
-    load.commit         warehouse load + offset commit
-    serving.fold        materialized-view delta fold (per epoch advance)
-    query.batch         batched report plan execute  (per coalesced batch)
-    checkpoint.step     durability journal append
-    repartition.*       plan / reroute / migrate phases
+    ingest.pump           master pumps under the cache lock   rows
+    ingest.fetch          broker poll -> hand-off             records, batch
+    transform.queue_wait  hand-off stamp -> transform's get   records, batch
+    transform.dispatch    one batch's device transform        records, batch
+      transform.snapshot  cache lock + both cache snapshots   records, batch,
+                                                              upload_bytes
+      transform.launch    pack, H2D, jit call, async D2H      records, batch
+    load.queue_wait       hand-off stamp -> load's get        records, batch
+    load.commit           warehouse load + offset commit      records, batch
+      load.to_host        the step's one blocking D2H sync    records, batch
+      load.warehouse      partition sort, warehouse lock,     records, batch
+                          commit, serving delta publish
+    load.retry            late records' transform + load      records, batch
+                          (load.to_host, load.warehouse nest here)
+    serving.fold          materialized-view delta fold        deltas, rows, epoch
+    query.batch           batched report plan execute         queries, epoch
+    checkpoint.step       durability journal append
+    repartition.*         plan / reroute / migrate phases
+    control.decide        one autonomous policy decision
+
+``records`` on a nested seam is the count of its parent (records
+fetched; records loaded under ``load.commit``); on ``load.retry``, the
+late records popped. The two ``*.queue_wait`` spans are recorded with
+explicit times (``record()``): they start on the producing thread, so
+they are not profiler annotations and carry no ``cpu_s``.
 
 Lanes: a span lands in the lane (Chrome ``tid``) named after its thread
 (worker stage threads are named ``w0.ingest`` etc.), so the Perfetto
@@ -58,9 +83,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _NullTracer:
-    """Disabled tracer: ``span()``/``instant()`` are allocation-free
-    no-ops (pinned by a tracemalloc test). Default for every component's
-    ``tracer`` attribute — the same seam as ``NULL_INJECTOR``."""
+    """Disabled tracer: ``span()``/``instant()``/``record()`` are
+    allocation-free no-ops (pinned by a tracemalloc test). Default for
+    every component's ``tracer`` attribute — the same seam as
+    ``NULL_INJECTOR``."""
 
     __slots__ = ()
     enabled = False
@@ -71,17 +97,23 @@ class _NullTracer:
     def instant(self, name: str, lane: Optional[str] = None) -> None:
         return None
 
+    def record(self, name: str, t_start: float, t_end: float,
+               **args) -> None:
+        return None
+
 
 NULL_TRACER = _NullTracer()
 
 
 class _Span(object):
-    """One live span: context manager capturing wall interval + optional
-    args; appended to the tracer's event list (under its lock) on exit.
-    ``drop()`` cancels recording — used to skip empty broker polls so
-    idle traces stay readable."""
+    """One live span: context manager capturing the wall interval, the
+    thread CPU time over it (``cpu_s``, when the tracer has a CPU clock)
+    and optional args; appended to the tracer's event list (under its
+    lock) on exit. ``drop()`` cancels recording — used to skip empty
+    broker polls so idle traces stay readable."""
 
-    __slots__ = ("_tracer", "name", "lane", "_t0", "_args", "_dropped")
+    __slots__ = ("_tracer", "name", "lane", "_t0", "_c0", "_args",
+                 "_dropped")
 
     def __init__(self, tracer: "StageTracer", name: str,
                  lane: Optional[str]):
@@ -89,6 +121,7 @@ class _Span(object):
         self.name = name
         self.lane = lane
         self._t0 = 0.0
+        self._c0 = 0.0
         self._args: Optional[Dict[str, object]] = None
         self._dropped = False
 
@@ -102,27 +135,61 @@ class _Span(object):
         self._dropped = True
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._tracer._clock()
+        tr = self._tracer
+        self._t0 = tr._clock()
+        if tr._cpu_clock is not None:
+            self._c0 = tr._cpu_clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         if not self._dropped:
-            t1 = self._tracer._clock()
-            self._tracer._record(
-                self.name, self.lane or threading.current_thread().name,
-                self._t0, t1 - self._t0, self._args)
+            tr = self._tracer
+            # CPU interval read inside the wall interval: cpu_s <= dur
+            if tr._cpu_clock is not None:
+                self.put("cpu_s", tr._cpu_clock() - self._c0)
+            t1 = tr._clock()
+            tr._record(self.name,
+                       self.lane or threading.current_thread().name,
+                       self._t0, t1 - self._t0, self._args)
+        return False
+
+
+class _AnnotatedSpan(_Span):
+    """The span ``StageTracer.span()`` returns: a ``_Span`` that is also a
+    ``jax.profiler.TraceAnnotation`` over its interval, so a profiler
+    trace of the running system shows the stage seams on the device
+    trace's clock. The base ``_Span`` stays unannotated, so a subclass
+    that annotates by itself shows each span once, not twice."""
+
+    __slots__ = ("_ann",)
+
+    def __enter__(self) -> "_AnnotatedSpan":
+        self._ann = self._tracer._annotation(self.name)
+        self._ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        self._ann.__exit__(*exc)
         return False
 
 
 class StageTracer:
     """Collects spans from every pipeline thread; lock guards only the
     event-list append (the measured interval is computed outside it).
-    Export with ``to_chrome()`` / ``export_chrome_trace()``."""
+    ``clock`` times the spans (``perf_counter``, the profiler's host
+    clock); ``cpu_clock`` gives each span its ``cpu_s`` (None: no CPU
+    time). Export with ``to_chrome()`` / ``export_chrome_trace()``."""
 
     enabled = True
 
-    def __init__(self, clock=time.perf_counter, max_events: int = 1 << 20):
+    def __init__(self, clock=time.perf_counter, max_events: int = 1 << 20,
+                 cpu_clock=time.thread_time):
+        # imported here: importing the tracer does not import JAX
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self._clock = clock
+        self._cpu_clock = cpu_clock
         self._t0 = clock()
         self._lock = threading.Lock()
         self._events: List[tuple] = []   # (ph, name, lane, t_start, dur, args)
@@ -131,7 +198,15 @@ class StageTracer:
 
     # ------------------------------------------------------------ write side
     def span(self, name: str, lane: Optional[str] = None) -> _Span:
-        return _Span(self, name, lane)
+        return _AnnotatedSpan(self, name, lane)
+
+    def record(self, name: str, t_start: float, t_end: float,
+               **args) -> None:
+        """A span whose times the caller took on this tracer's clock, in
+        the calling thread's lane: for an interval that began on another
+        thread (a hand-off wait), which no context manager can enclose."""
+        self._record(name, threading.current_thread().name, t_start,
+                     t_end - t_start, args or None)
 
     def instant(self, name: str, lane: Optional[str] = None) -> None:
         self._record(name, lane or threading.current_thread().name,

@@ -148,13 +148,27 @@ class SimulatedCluster:
 # same estimator on the same clock (repro.core.metrics)
 _percentiles_ms = percentiles_ms
 
+# the ``batch`` argument of the retry path's spans: its late records come
+# from many fetches, so no one fetch ordinal names them
+RETRY_BATCH = -1
+
+
+def _tag(span, records: int, batch: int) -> None:
+    """The two arguments every per-batch worker-stage span carries."""
+    span.put("records", records)
+    span.put("batch", batch)
+
 
 @dataclasses.dataclass
 class _Work:
-    """Ingest -> transform hand-off: one coalesced fetch (uncommitted)."""
+    """Ingest -> transform hand-off: one coalesced fetch (uncommitted),
+    its fetch ordinal and the ``perf_counter`` at which it was ready to
+    hand off (the start of its ``transform.queue_wait``)."""
     topic: str
     batch: RecordBatch
     counts: Dict[int, int]
+    seq: int
+    t_ready: float
 
 
 @dataclasses.dataclass
@@ -169,10 +183,16 @@ class _Transformed:
     ``batch``/``block`` carry only the transformable records; ``dead``
     (usually None) carries poison records the transform stage isolated —
     the load stage quarantines them to the worker's dead-letter buffer
-    and still commits their offsets (quarantined == handled)."""
+    and still commits their offsets (quarantined == handled).
+
+    ``seq`` is the fetch ordinal; ``t_ready`` the ``perf_counter`` at
+    which the block was ready to hand off (the start of its
+    ``load.queue_wait``)."""
     topic: str
     batch: RecordBatch
     counts: Dict[int, int]
+    seq: int
+    t_ready: float
     block: object                   # repro.core.backend.FactBlock (or None
                                     # when every record in the batch was
                                     # poison)
@@ -427,9 +447,16 @@ class WorkerRuntime:
         while not self.stop.is_set():
             self.beat("ingest")
             self._apply_control()
-            with self.cache_lock:
-                w.pump_master(pipe.master_topic_map["equipment"], w.equipment)
-                w.pump_master(pipe.master_topic_map["quality"], w.quality)
+            with self.tracer.span("ingest.pump") as sp:
+                with self.cache_lock:
+                    rows = (w.pump_master(pipe.master_topic_map["equipment"],
+                                          w.equipment)
+                            + w.pump_master(pipe.master_topic_map["quality"],
+                                            w.quality))
+                if rows:
+                    sp.put("rows", rows)
+                else:
+                    sp.drop()        # keep idle pumps out of traces
             got = 0
             for topic in pipe.operational_topics:
                 if self.stop.is_set():
@@ -460,14 +487,16 @@ class WorkerRuntime:
                     if not counts:
                         sp.drop()        # keep idle polling out of traces
                     else:
-                        sp.put("records", len(batch))
+                        _tag(sp, len(batch), self.fetched)
                 self.credits.refund(grant - len(batch))  # unused grant
                 if counts:
                     self.records_fetched += len(batch)
                     pipe.fault.trip(INGEST_FETCH)   # fetched, uncommitted
+                    seq = self.fetched
                     self.fetched += 1
                     if not self._put(self.transform_q,
-                                     _Work(topic, batch, counts)):
+                                     _Work(topic, batch, counts, seq,
+                                           time.perf_counter())):
                         self.items_dropped_ingest += 1   # shutdown only
                         self.records_dropped_ingest += len(batch)
                         self.credits.refund(len(batch))
@@ -477,7 +506,8 @@ class WorkerRuntime:
 
     # -------------------------------------------------------- stage: transform
     def _transform_body(self) -> None:
-        device = self.worker.backend.device
+        w, tr = self.worker, self.tracer
+        device = w.backend.device
         while True:
             self.beat("transform")
             item = self._get(self.transform_q)
@@ -485,20 +515,30 @@ class WorkerRuntime:
                 if self.stop.is_set():
                     return
                 continue
+            n, seq = len(item.batch), item.seq
+            tr.record("transform.queue_wait", item.t_ready,
+                      time.perf_counter(), records=n, batch=seq)
             # hold the cache lock only long enough to pin an immutable
             # snapshot; the dispatch itself runs lock-free, so the ingest
             # stage's master pumps overlap the numeric core instead of
             # queueing behind every dispatch
-            with self.tracer.span("transform.dispatch") as sp:
-                with self.cache_lock:
-                    eq = self.worker.equipment.snapshot_view(device)
-                    qu = self.worker.quality.snapshot_view(device)
-                good, block, dead = self._transform_quarantine(
-                    item.batch, eq, qu)
-                sp.put("records", len(item.batch))
+            with tr.span("transform.dispatch") as sp:
+                with tr.span("transform.snapshot") as ss:
+                    with self.cache_lock:
+                        eq = w.equipment.snapshot_view(device)
+                        qu = w.quality.snapshot_view(device)
+                        up = w.equipment.upload_bytes + w.quality.upload_bytes
+                    _tag(ss, n, seq)
+                    ss.put("upload_bytes", up)
+                with tr.span("transform.launch") as sl:
+                    good, block, dead = self._transform_quarantine(
+                        item.batch, eq, qu)
+                    _tag(sl, n, seq)
+                _tag(sp, n, seq)
             self.pipe.fault.trip(TRANSFORM_DONE)   # transformed, unloaded
             if not self._put(self.load_q,
                              _Transformed(item.topic, good, item.counts,
+                                          seq, time.perf_counter(),
                                           block, dead=dead)):
                 self.items_dropped_transform += 1        # shutdown only
                 self.records_dropped_transform += len(item.batch)
@@ -556,13 +596,17 @@ class WorkerRuntime:
         return good, block, (dead if len(dead) else None)
 
     # ------------------------------------------------------------- stage: load
-    def _load_and_record(self, batch: RecordBatch, block) -> int:
+    def _load_and_record(self, batch: RecordBatch, block, seq: int) -> int:
         """Commit-lock-held helper: materialize the device block (the
         step's ONE host↔device round trip — the async copy started at
         dispatch time has usually landed by now), buffer lates, load
-        facts + fused rollup, sample freshness. Returns records loaded."""
+        facts + fused rollup, sample freshness. Returns records loaded.
+        ``seq`` is the fetch ordinal the spans carry."""
         w = self.worker
-        facts, found = block.to_host()
+        with self.tracer.span("load.to_host") as sp:
+            facts, found = block.to_host()
+            rollup = block.rollup_host()
+            _tag(sp, int(np.count_nonzero(found)), seq)
         w.buffer.push(batch.filter(~found))
         good = facts[found]
         # join-level cache accounting (same counters the sequential worker
@@ -576,10 +620,12 @@ class WorkerRuntime:
         ev = log.event_times(batch.lsn[found])
         # event times ride into the warehouse so an attached serving layer
         # can stamp per-record report staleness on the same CDC clock
-        w.warehouse.load_partitioned(
-            good, self.pipe.cfg.n_partitions, event_times=ev,
-            rollup=block.rollup_host(),
-            routing_epoch=self.pipe.current_routing().epoch)
+        with self.tracer.span("load.warehouse") as sp:
+            w.warehouse.load_partitioned(
+                good, self.pipe.cfg.n_partitions, event_times=ev,
+                rollup=rollup,
+                routing_epoch=self.pipe.current_routing().epoch)
+            _tag(sp, len(good), seq)
         self.latency.add(log.clock() - ev)
         self.records_done += len(good)
         return len(good)
@@ -597,19 +643,21 @@ class WorkerRuntime:
                      if self.cap else None)
             ready = w.buffer.pop_ready(w.transformer.watermark(), limit)
             if len(ready):
-                device = w.backend.device
-                with self.cache_lock:
-                    eq = w.equipment.snapshot_view(device)
-                    qu = w.quality.snapshot_view(device)
-                # the retry path meets poison records too (a poison
-                # record that was merely *late* first) — same quarantine
-                good, block, dead = self._transform_quarantine(
-                    ready, eq, qu)
-                if dead is not None:
-                    w.dead_letter.push(dead, reason="transform-poison")
-                    w._c_dead.inc(len(dead))
-                if block is not None:
-                    self._load_and_record(good, block)
+                with self.tracer.span("load.retry") as sp:
+                    device = w.backend.device
+                    with self.cache_lock:
+                        eq = w.equipment.snapshot_view(device)
+                        qu = w.quality.snapshot_view(device)
+                    # the retry path meets poison records too (a poison
+                    # record that was merely *late* first) — same quarantine
+                    good, block, dead = self._transform_quarantine(
+                        ready, eq, qu)
+                    if dead is not None:
+                        w.dead_letter.push(dead, reason="transform-poison")
+                        w._c_dead.inc(len(dead))
+                    if block is not None:
+                        self._load_and_record(good, block, RETRY_BATCH)
+                    _tag(sp, len(ready), RETRY_BATCH)
             self.retry_inflight = 0
 
     def _load_body(self) -> None:
@@ -623,10 +671,14 @@ class WorkerRuntime:
                 continue
             n_dead = len(item.dead) if item.dead is not None else 0
             n_total = len(item.batch) + n_dead
+            self.tracer.record("load.queue_wait", item.t_ready,
+                               time.perf_counter(), records=n_total,
+                               batch=item.seq)
             with self.commit_lock:
                 if not self.dead:
                     with self.tracer.span("load.commit") as sp:
-                        done = (self._load_and_record(item.batch, item.block)
+                        done = (self._load_and_record(item.batch, item.block,
+                                                      item.seq)
                                 if item.block is not None else 0)
                         if item.dead is not None:
                             # poison quarantine: park the records, count
@@ -645,7 +697,7 @@ class WorkerRuntime:
                             self.worker.queue.commit(self.worker.group,
                                                      item.topic, p, c)
                         self.pipe.fault.trip(COMMIT_POST)
-                        sp.put("records", done)
+                        _tag(sp, done, item.seq)
                 # retire AFTER the lates are buffered: between push and
                 # retirement the records are double-counted (buffer AND
                 # in-flight), which errs on the safe side of headroom

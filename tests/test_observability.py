@@ -131,7 +131,7 @@ def _tick_clock(step_s=0.5e-3):
 def test_tracer_golden_chrome_trace_with_nesting():
     """Deterministic clock -> byte-stable Chrome-trace JSON: nested spans
     close inner-first, lanes become labeled tids, args ride along."""
-    tracer = StageTracer(clock=_tick_clock())        # _t0 = 0.0
+    tracer = StageTracer(clock=_tick_clock(), cpu_clock=None)   # _t0 = 0.0
     with tracer.span("query.batch", lane="serving") as outer:   # t=0.5ms
         with tracer.span("serving.fold", lane="serving"):       # t=1.0ms
             pass                                                # t=1.5ms
@@ -192,33 +192,96 @@ def test_tracer_export_file(tmp_path):
 
 def test_null_tracer_zero_allocation():
     """The disabled seam allocates NOTHING per span: every call site gets
-    the one shared _NullSpan. Pinned with tracemalloc."""
+    the one shared _NullSpan, ``record()`` keeps nothing, and the worker
+    step's nested call sites (``_tag`` on nested spans, hand-off waits)
+    keep nothing either. Pinned with tracemalloc."""
+    import repro.observability.tracer as tracer_mod
+    import repro.runtime.cluster as cluster_mod
+    from repro.runtime.cluster import _tag
     tr = NULL_TRACER
     assert tr.span("warmup") is _NULL_SPAN           # shared singleton
-    for _ in range(100):                             # warm any caches
+    assert tr.record("warmup", 0.0, 1.0, records=1, batch=0) is None
+
+    def step(k):
         with tr.span("x") as sp:
             sp.put("k", 1)
             sp.drop()
         tr.instant("y")
-    import repro.observability.tracer as tracer_mod
+        tr.record("transform.queue_wait", 0.0, time.perf_counter(),
+                  records=4096, batch=k)
+        with tr.span("transform.dispatch") as sp:
+            with tr.span("transform.snapshot") as ss:
+                _tag(ss, 4096, k)
+                ss.put("upload_bytes", 0)
+            with tr.span("transform.launch") as sl:
+                _tag(sl, 4096, k)
+            _tag(sp, 4096, k)
+
+    for k in range(100):                             # warm any caches
+        step(k)
     tracemalloc.start()
     snap1 = tracemalloc.take_snapshot()
-    for _ in range(10_000):
-        with tr.span("x") as sp:
-            sp.put("k", 1)
-            sp.drop()
-        tr.instant("y")
+    for k in range(10_000):
+        step(k)
     snap2 = tracemalloc.take_snapshot()
     tracemalloc.stop()
+    files = (tracer_mod.__file__, cluster_mod.__file__, __file__)
     grown = sum(s.size_diff for s in snap2.compare_to(snap1, "filename")
-                if s.traceback[0].filename == tracer_mod.__file__
-                and s.size_diff > 0)
-    # zero PER-SPAN allocation: 10k spans may leave at most a constant
+                if s.traceback[0].filename in files and s.size_diff > 0)
+    # zero PER-SPAN allocation: 10k steps may leave at most a constant
     # few transient blocks (bound methods caught mid-flight by the
-    # snapshot), never anything proportional to the span count. One
+    # snapshot), never anything proportional to the step count. One
     # real span object per iteration would show >= 560 KB here.
     assert grown < 256
     assert tr.enabled is False
+
+
+@pytest.mark.parametrize("kind", ["stage_tracer", "bench_annotated"])
+def test_spans_are_profiler_annotations_once(kind, tmp_path):
+    """Under a ``jax.profiler`` trace on the CPU, every recorded span is
+    one host annotation, both with the program's own ``StageTracer`` and
+    with the benchmark's ``annotated_tracer`` (which annotates by
+    itself): each span shows once, never twice. ``record()`` spans are
+    not annotations."""
+    import jax
+    from jax.profiler import ProfileData
+    if kind == "stage_tracer":
+        tracer = StageTracer()
+    else:
+        import sys
+        from pathlib import Path
+        root = str(Path(__file__).resolve().parents[1])
+        if root not in sys.path:
+            sys.path.insert(0, root)
+        from bench.harness import annotated_tracer
+        tracer = annotated_tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for k in range(7):
+            tracer.record("transform.queue_wait", time.perf_counter(),
+                          time.perf_counter(), records=3, batch=k)
+            with tracer.span("transform.dispatch"):
+                with tracer.span("transform.snapshot"):
+                    pass
+                with tracer.span("transform.launch"):
+                    jax.numpy.zeros(3).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    seen = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    seen[ev.name] = seen.get(ev.name, 0) + 1
+    recorded = {}
+    for ev in tracer.events():
+        recorded[ev[1]] = recorded.get(ev[1], 0) + 1
+    assert recorded["transform.dispatch"] == 7
+    for name in ("transform.dispatch", "transform.snapshot",
+                 "transform.launch"):
+        assert seen.get(name) == recorded[name], (name, seen.get(name))
+    assert "transform.queue_wait" not in seen
 
 
 # ---------------------------------------------------- bounded reservoir
@@ -323,6 +386,123 @@ def test_cluster_trace_covers_all_six_stage_seams(tmp_path):
         if e["ph"] == "X":
             assert e["dur"] >= 0.0 and e["ts"] >= 0.0
     json.loads(json.dumps(doc))                      # round-trips
+
+
+# the per-batch seams of a worker step: (name -> parents it nests in)
+_NESTED = {"transform.snapshot": ("transform.dispatch",),
+           "transform.launch": ("transform.dispatch",),
+           "load.to_host": ("load.commit", "load.retry"),
+           "load.warehouse": ("load.commit", "load.retry")}
+_PER_BATCH = ("ingest.fetch", "transform.dispatch", "load.commit",
+              "load.retry") + tuple(_NESTED)
+
+
+class _HeldSource:
+    """Collects the sampler's change batches, to apply them in phases."""
+
+    def __init__(self):
+        self.batches = []
+
+    def apply(self, batch):
+        self.batches.append(batch)
+
+
+def _traced_cluster_events():
+    """Two workers drain 3,000 records; the 5% late master tail reaches
+    the log only once the production facts have been processed, so the
+    late records go through the retry path."""
+    cfg, src, sampler = _build(2)
+    tracer = StageTracer()
+    pipe = DODETLPipeline(cfg, src, n_workers=2, tracer=tracer)
+    # a per-partition cap splits each worker's share into several batches
+    cluster = ConcurrentCluster(pipe, max_records_per_partition=100)
+    held = _HeldSource()
+    sampler.generate(held)
+    for batch in held.batches[:3]:      # masters (less the tail), facts
+        src.apply(batch)
+    cluster.start()
+    try:
+        early = cluster.run_until_idle(timeout=60, stall_s=1.0)
+        for batch in held.batches[3:]:  # the late master tail
+            src.apply(batch)
+        done = cluster.run_until_idle(timeout=60)
+    finally:
+        cluster.stop_all()
+    assert early < 3000 and done == 3000
+    return tracer.events()
+
+
+def test_cluster_trace_nests_worker_step_seams():
+    """The worker step's sub-spans: every new seam appears; each nested
+    seam lies inside its parent's interval on the same lane, with the
+    parent's batch; the children of one parent sum to no more than it;
+    every per-batch span carries ``records``, ``batch`` and a ``cpu_s``
+    within [0, dur]; each hand-off wait starts at or after the start of
+    the stage that produced its batch."""
+    events = [e for e in _traced_cluster_events() if e[0] == "X"]
+    names = {e[1] for e in events}
+    assert {"ingest.pump", "transform.queue_wait", "transform.snapshot",
+            "transform.launch", "load.queue_wait", "load.to_host",
+            "load.warehouse", "load.retry"} <= names
+
+    children = {}                       # parent event index -> child durs
+    for e in events:
+        _, name, lane, t0, dur, args = e
+        if name in _PER_BATCH:
+            assert {"records", "batch", "cpu_s"} <= set(args), e
+            assert 0.0 <= args["cpu_s"] <= dur, e
+        if name == "ingest.pump":
+            assert args["rows"] > 0 and 0.0 <= args["cpu_s"] <= dur
+        if name in _NESTED:
+            parents = [i for i, p in enumerate(events)
+                       if p[1] in _NESTED[name] and p[2] == lane
+                       and p[3] <= t0 and t0 + dur <= p[3] + p[4]]
+            assert len(parents) == 1, e
+            parent = events[parents[0]]
+            assert parent[5]["batch"] == args["batch"], (e, parent)
+            children.setdefault(parents[0], []).append(dur)
+    assert children
+    for i, durs in children.items():
+        assert sum(durs) <= events[i][4], (events[i], durs)
+
+    def start_of(name, worker, batch):
+        found = [e[3] for e in events if e[1] == name
+                 and e[2].split(".")[0] == worker
+                 and e[5]["batch"] == batch]
+        assert len(found) == 1, (name, worker, batch)
+        return found[0]
+
+    waits = 0
+    for _, name, lane, t0, dur, args in events:
+        if name.endswith(".queue_wait"):
+            assert {"records", "batch"} <= set(args)
+            assert dur >= 0.0
+            worker = lane.split(".")[0]
+            cause = ("ingest.fetch" if name == "transform.queue_wait"
+                     else "transform.launch")
+            assert t0 >= start_of(cause, worker, args["batch"])
+            waits += 1
+    assert waits >= 2
+
+
+def test_snapshot_view_reports_upload_bytes():
+    """``transform.snapshot``'s ``upload_bytes``: the first device
+    snapshot uploads all three arrays, a memoized one nothing, and an
+    update of existing keys only the values and txn times."""
+    from repro.core.cache import InMemoryTable
+    tbl = InMemoryTable(64, backend="jax")
+    keys = np.arange(10, dtype=np.int64)
+    rows = np.ones((10, tbl.width), np.float32)
+    tbl.upsert(keys, rows, np.arange(10, dtype=np.int64))
+    snap = tbl.snapshot_view(True)
+    k, v, t = snap.device_state()
+    assert tbl.upload_bytes == k.nbytes + v.nbytes + t.nbytes > 0
+    assert tbl.snapshot_view(True) is snap and tbl.upload_bytes == 0
+    tbl.upsert(keys, 2 * rows, np.arange(10, dtype=np.int64) + 100)
+    tbl.snapshot_view(True)
+    assert tbl.upload_bytes == v.nbytes + t.nbytes
+    tbl.snapshot_view(False)                 # host snapshot: a copy
+    assert tbl.upload_bytes == 0
 
 
 def test_health_consistent_during_rebalance_and_checkpoint(tmp_path):
