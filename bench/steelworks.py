@@ -350,6 +350,15 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_process: float,
         loaded_close = pipe.warehouse.rows_loaded
         produced_close = produced()
         unfolded_close = loaded_close - engine.snapshot().rows_folded
+        backlog_left = None
+        if mode == "backlog":
+            # the broker lag at the window's close, traced or not. Nothing
+            # after a backlog's window needs loading, so the stage threads
+            # stop here (stop_all joins them): neither the trace's
+            # reduction nor the final fold waits on records loaded late
+            backlog_left = int(cluster._operational_lag())
+            for rt in cluster.runtimes.values():
+                rt.stop.set()
         device_trace = prof.stop_and_reduce() if prof is not None else None
 
         # ---- after the window: let every due record and query finish
@@ -359,7 +368,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t_process: float,
             unfolded_open=unfolded_open, unfolded_close=unfolded_close)
         if mode == "backlog":
             # run.py refuses a run whose backlog emptied in the window
-            notes["backlog_left"] = int(cluster._operational_lag())
+            notes["backlog_left"] = backlog_left
         else:
             if not stream.join(DRAIN_TIMEOUT_S):
                 raise BenchError("the generator did not finish")

@@ -110,10 +110,13 @@ def test_result_line(broken, copied_cache_uploads, monkeypatch):
     spec = harness.load_cell(cell)
     fault = load_drops_half(monkeypatch) if broken else None
     over = dict(SMALL[spec["traffic"]["mode"]])
+    seconds = 1.5
     if spec["traffic"]["mode"] == "backlog":
-        # run_cell refuses a backlog that empties: fill past the CPU's drain
-        over["fill_records_s"] = 150000
-    line = run.run_cell(cell, 987654321013, 1.5, False,
+        # run_cell refuses a backlog that empties: 675,000 records in a
+        # 1.5 s run, over 3x what the CPU drains in it
+        over.update(fill_records_s=450000, warmup_s=0.5)
+        seconds = 1.0
+    line = run.run_cell(cell, 987654321013, seconds, False,
                         require_tpu=False, t_process=time.perf_counter(),
                         fault=fault, overrides=over)
     res = json.loads(line)
