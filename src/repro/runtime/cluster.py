@@ -185,9 +185,10 @@ class _Transformed:
     the load stage quarantines them to the worker's dead-letter buffer
     and still commits their offsets (quarantined == handled).
 
-    ``seq`` is the fetch ordinal; ``t_ready`` the ``perf_counter`` at
-    which the block was ready to hand off (the start of its
-    ``load.queue_wait``)."""
+    ``seq`` is the fetch ordinal of the first hand-off it covers;
+    ``handoffs`` how many consecutive ``_Work`` hand-offs the transform
+    stage merged into it; ``t_ready`` the ``perf_counter`` at which the
+    block was ready to hand off (the start of its ``load.queue_wait``)."""
     topic: str
     batch: RecordBatch
     counts: Dict[int, int]
@@ -197,6 +198,7 @@ class _Transformed:
                                     # when every record in the batch was
                                     # poison)
     dead: object = None             # RecordBatch of quarantined records
+    handoffs: int = 1
 
 
 @dataclasses.dataclass
@@ -220,9 +222,10 @@ class WorkerRuntime:
       ingest    pumps master topics into the worker caches, then fetches
                 operational partitions (advancing broker READ positions,
                 committing nothing) and hands each coalesced batch off;
-      transform one backend dispatch per hand-off batch (GIL released in
-                the numeric core, so transforms of different workers
-                genuinely overlap);
+      transform one backend dispatch per run of queued same-topic hand-offs
+                (one per hand-off unless a backlog queued several; GIL
+                released in the numeric core, so transforms of different
+                workers genuinely overlap);
       load      the ONLY mutating stage: under the worker's commit lock it
                 buffers late records, loads facts, commits offsets and
                 records freshness samples — one atomic unit, so a kill
@@ -286,6 +289,9 @@ class WorkerRuntime:
         shard = pipe.metrics.shard(worker.name)
         self.mshard = shard
         shard.register_histogram("freshness", self.latency)
+        # hand-offs that rode in an earlier hand-off's dispatch (transform
+        # thread): 0 while nothing queues up behind a dispatch
+        self._c_coalesced = shard.counter("worker.handoffs_coalesced")
         shard.gauge_fn("transform_q_depth", self.transform_q.qsize)
         shard.gauge_fn("load_q_depth", self.load_q.qsize)
         shard.gauge_fn("in_flight", self.in_flight)
@@ -505,19 +511,60 @@ class WorkerRuntime:
                 time.sleep(pipe.cfg.idle_backoff_s)
 
     # -------------------------------------------------------- stage: transform
+    def _coalesce(self, first: _Work):
+        """``first`` and the run of same-topic hand-offs already queued
+        behind it, taken without blocking, as ``(run, carry)``. ``carry``
+        is the item that ended the run (another topic, or one that would
+        push the merged batch past the late buffer's capacity or the
+        per-partition cap's step bound), for the next dispatch; None when
+        the queue ran dry. Ingest already counted every queued record
+        against the late buffer's headroom, so a merged batch, even if
+        all late, fits the buffer as its parts did; the explicit limit
+        keeps every dispatch within the sizes one fetch could reach."""
+        limit = self.pipe.cfg.buffer_capacity
+        if self.cap is not None:
+            limit = min(limit, self.cap * max(1, len(self.worker.partitions)))
+        run, n = [first], len(first.batch)
+        while True:
+            try:
+                item = self.transform_q.get_nowait()
+            except queue_mod.Empty:
+                return run, None
+            if item.topic != first.topic or n + len(item.batch) > limit:
+                return run, item
+            run.append(item)
+            n += len(item.batch)
+
     def _transform_body(self) -> None:
         w, tr = self.worker, self.tracer
         device = w.backend.device
+        carry: Optional[_Work] = None
         while True:
             self.beat("transform")
-            item = self._get(self.transform_q)
+            item = carry or self._get(self.transform_q)
             if item is None:
                 if self.stop.is_set():
                     return
                 continue
-            n, seq = len(item.batch), item.seq
-            tr.record("transform.queue_wait", item.t_ready,
-                      time.perf_counter(), records=n, batch=seq)
+            run, carry = self._coalesce(item)
+            now = time.perf_counter()
+            for it in run:
+                tr.record("transform.queue_wait", it.t_ready, now,
+                          records=len(it.batch), batch=it.seq)
+            if len(run) == 1:
+                batch, counts = item.batch, item.counts
+            else:
+                # fetch order kept; one commit of a partition's summed
+                # count equals the separate commits (commit advances by
+                # a count)
+                batch = RecordBatch.concat([it.batch for it in run])
+                counts = {}
+                for it in run:
+                    for p, c in it.counts.items():
+                        counts[p] = counts.get(p, 0) + c
+                self._c_coalesced.inc(len(run) - 1)
+            # a merged dispatch is tagged with its first hand-off's ordinal
+            n, seq = len(batch), item.seq
             # hold the cache lock only long enough to pin an immutable
             # snapshot; the dispatch itself runs lock-free, so the ingest
             # stage's master pumps overlap the numeric core instead of
@@ -532,17 +579,18 @@ class WorkerRuntime:
                     ss.put("upload_bytes", up)
                 with tr.span("transform.launch") as sl:
                     good, block, dead = self._transform_quarantine(
-                        item.batch, eq, qu)
+                        batch, eq, qu)
                     _tag(sl, n, seq)
                 _tag(sp, n, seq)
+                sp.put("handoffs", len(run))
             self.pipe.fault.trip(TRANSFORM_DONE)   # transformed, unloaded
             if not self._put(self.load_q,
-                             _Transformed(item.topic, good, item.counts,
-                                          seq, time.perf_counter(),
-                                          block, dead=dead)):
-                self.items_dropped_transform += 1        # shutdown only
-                self.records_dropped_transform += len(item.batch)
-                self.credits.refund(len(item.batch))
+                             _Transformed(item.topic, good, counts, seq,
+                                          time.perf_counter(), block,
+                                          dead=dead, handoffs=len(run))):
+                self.items_dropped_transform += len(run)  # shutdown only
+                self.records_dropped_transform += n
+                self.credits.refund(n)
 
     def _transform_quarantine(self, batch: RecordBatch, eq, qu):
         """ONE fused transform+rollup dispatch, NO host sync: the block
@@ -706,8 +754,10 @@ class WorkerRuntime:
                 # coordinator quiescing on it (under this lock) is
                 # guaranteed to also observe the item's offset commits —
                 # bumping it first let a rebalance read a stale committed
-                # offset and replay a whole partition at its new owner
-                self.completed += 1
+                # offset and replay a whole partition at its new owner.
+                # It counts fetch ordinals, so a merged item retires all
+                # the hand-offs it covers
+                self.completed += item.handoffs
             # refund the full fetch (lates/quarantined included: they
             # left the in-flight window — lates are buffer-bounded, not
             # credit-bounded)

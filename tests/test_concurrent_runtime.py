@@ -195,3 +195,130 @@ def test_cdc_event_times_monotonic():
     assert len(stamps) == 15
     assert (np.diff(stamps) >= 0).all()
     assert (stamps <= src.log.clock()).all()
+
+
+# ---------------------------------------------- transform-stage coalescing
+def _traced_pipe(n_workers, n_records, late_frac=0.05):
+    from repro.observability.tracer import StageTracer
+    cfg, src, sampler, _ = build(n_workers, n_records, late_frac=late_frac)
+    tracer = StageTracer()
+    pipe = DODETLPipeline(cfg, src, n_workers=n_workers, tracer=tracer)
+    return cfg, src, sampler, pipe, tracer
+
+
+class _HeldSource:
+    """Collects the sampler's change batches, to apply them in pieces."""
+
+    def __init__(self):
+        self.batches = []
+
+    def apply(self, batch):
+        self.batches.append(batch)
+
+
+def _wait_loaded(cluster, n, timeout=30.0):
+    deadline = time.perf_counter() + timeout
+    while cluster.records_done() < n:
+        assert time.perf_counter() < deadline, (cluster.records_done(), n)
+        time.sleep(0.002)
+
+
+def _backlog(cluster_kw=None, resize=None):
+    """A pre-extracted 3,000-record backlog; ``resize`` is applied once
+    some hand-offs have merged, while the backlog still drains."""
+    n = 3000
+    n_workers = 2 if resize else 1
+    cfg, src, sampler, pipe, tracer = _traced_pipe(n_workers, n)
+    sampler.generate(src)
+    pipe.extract()                      # everything queued before start
+    cluster = ConcurrentCluster(pipe, poll_cdc=False, **(cluster_kw or {}))
+    cluster.start()
+    if resize:
+        deadline = time.perf_counter() + 30.0
+        while not pipe.metrics.counters().get("worker.handoffs_coalesced"):
+            assert time.perf_counter() < deadline, "nothing merged"
+            time.sleep(0.001)
+        assert cluster.records_done() < n
+        resize(cluster)
+    done = cluster.run_until_idle(timeout=60)
+    return cfg, pipe, cluster, tracer, n, done
+
+
+def _trickle():
+    """16 appends of 10 records, each to one unit (so one partition and
+    one fetch), the cluster idle before the next."""
+    cfg, src, sampler, pipe, tracer = _traced_pipe(1, 400, late_frac=0.0)
+    held = _HeldSource()
+    sampler.generate(held)
+    masters, facts = held.batches[:2], held.batches[2]
+    for batch in masters:
+        src.apply(batch)
+    cluster = ConcurrentCluster(pipe)
+    cluster.start()
+    n = 0
+    for unit in range(8):
+        rows = np.nonzero(facts.business_key == unit)[0]
+        for lo in (0, 10):
+            src.apply(facts.take(rows[lo:lo + 10]))
+            n += 10
+            _wait_loaded(cluster, n)
+    done = cluster.run_until_idle(timeout=60)
+    return cfg, pipe, cluster, tracer, n, done
+
+
+_COALESCE_CASES = {
+    "backlog": _backlog,
+    "trickle": _trickle,
+    "capped": lambda: _backlog({"max_records_per_partition": 100}),
+    "scale_up": lambda: _backlog(resize=lambda c: c.scale_to(3)),
+    "failover": lambda: _backlog(resize=lambda c: c.fail_workers(["w1"])),
+}
+
+
+@pytest.mark.parametrize("case", list(_COALESCE_CASES))
+def test_transform_coalesces_queued_handoffs(case):
+    """The transform stage merges the same-topic hand-offs already queued
+    behind the one it takes into one dispatch: a backlog merges, never
+    past the late buffer's capacity nor the per-partition cap's step
+    bound; a trickle (one hand-off queued at a time) never merges; a
+    rebalance while hand-offs merge loses and duplicates nothing, since
+    a merged item retires every fetch ordinal it covers."""
+    cfg, pipe, cluster, tracer, n, done = _COALESCE_CASES[case]()
+    try:
+        alive = [rt for rt in cluster.runtimes.values() if not rt.dead]
+        assert all(rt.in_flight() == 0 for rt in alive)
+    finally:
+        cluster.stop_all()
+    assert done == n
+    assert pipe.warehouse.rows_loaded == n          # none lost, none twice
+
+    events = [e for e in tracer.events() if e[0] == "X"]
+    dispatch = [e[5] for e in events if e[1] == "transform.dispatch"]
+    waits = [e[5] for e in events if e[1] == "transform.queue_wait"]
+    merged = pipe.metrics.counters()["worker.handoffs_coalesced"]
+    # every queued hand-off waited once and rode exactly one dispatch
+    assert sum(d["handoffs"] for d in dispatch) == len(waits)
+    assert sum(d["records"] for d in dispatch) == sum(
+        w["records"] for w in waits)
+    assert merged == len(waits) - len(dispatch)
+    assert all(d["records"] <= cfg.buffer_capacity for d in dispatch)
+
+    if case == "trickle":
+        assert len(dispatch) == 16
+        assert all(d["handoffs"] == 1 for d in dispatch)
+        assert merged == 0
+        return
+    assert pipe.warehouse.canonical_fact_table().tobytes() == \
+        sequential_oracle(n).warehouse.canonical_fact_table().tobytes()
+    if case == "capped":
+        assert all(d["records"] <= 100 * cfg.n_partitions for d in dispatch)
+        return
+    assert merged > 0
+    assert any(d["handoffs"] > 1 for d in dispatch)
+    if case == "backlog":
+        (rt,) = alive
+        w = rt.worker
+        committed = sum(w.queue.committed(w.group, t, p)
+                        for t in pipe.operational_topics
+                        for p in range(cfg.n_partitions))
+        assert committed == n
