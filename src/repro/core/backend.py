@@ -501,39 +501,46 @@ class ComputeBackend:
         Returns host (values [n, W] f32, found [n] bool, txn [n])."""
         raise NotImplementedError
 
-    def transform_block(self, prod: np.ndarray, equipment, quality, *,
-                        join_depth: int = 1,
+    def transform_block(self, payload: np.ndarray, *caches,
+                        schema: str = "steelworks", join_depth: int = 1,
                         n_units: Optional[int] = None) -> FactBlock:
-        """Fused fact-grain transform of production payloads [n, 8] against
-        the ``InMemoryTable`` caches, returned as a device-resident
-        ``FactBlock`` (NO host sync). With ``n_units`` set, the SAME
-        dispatch also produces the per-unit KPI rollup
-        (``FactBlock.rollup_host()`` — ``segment_reduce`` semantics over
-        the block's valid facts). ``join_depth > 1`` replays the probe
-        chain (§4.1.4 complexity knob — numerically a no-op, cost is the
-        point)."""
+        """Fused transform of one block of operational payloads against
+        the worker's caches (``InMemoryTable`` objects or their snapshots, in
+        the configuration's ``cached_tables`` order), returned as a
+        device-resident ``FactBlock`` (NO host sync). The configuration's
+        ``schema`` picks the body:
+
+        * ``steelworks``: production payloads [n, 8] f32 against the
+          equipment and quality caches -> OEE facts [n, 10]. With
+          ``n_units`` set, the SAME dispatch also produces the per-unit
+          KPI rollup (``FactBlock.rollup_host()`` — ``segment_reduce``
+          semantics over the block's valid facts). ``join_depth > 1``
+          replays the probe chain (§4.1.4 complexity knob — numerically a
+          no-op, cost is the point);
+        * ``tpcdi-dimtrade``: Trade CDC payloads [n, 16] i32 against the
+          DimAccount (point-in-time) and DimSecurity caches -> DimTrade
+          rows [n, 21] i32 (``repro.core.dimtrade``)."""
         raise NotImplementedError
 
-    def transform(self, prod: np.ndarray, equipment, quality, *,
-                  join_depth: int = 1
+    def transform(self, payload: np.ndarray, *caches, join_depth: int = 1,
+                  schema: str = "steelworks"
                   ) -> Tuple[np.ndarray, np.ndarray]:
         """Host-convenience transform: ``transform_block`` + an immediate
-        ``to_host()``. Returns host (facts [n, N_FACT] f32, found [n]
-        bool). The device-resident hot path uses ``transform_block``
-        directly and defers the sync to the warehouse-load boundary."""
-        return self.transform_block(prod, equipment, quality,
+        ``to_host()``. Returns host (rows, found [n] bool). The
+        device-resident hot path uses ``transform_block`` directly and
+        defers the sync to the warehouse-load boundary."""
+        return self.transform_block(payload, *caches, schema=schema,
                                     join_depth=join_depth).to_host()
 
-    def transform_and_rollup(self, prod: np.ndarray, equipment, quality, *,
-                             n_units: int,
+    def transform_and_rollup(self, prod: np.ndarray, *caches, n_units: int,
                              join_depth: int = 1) -> FactBlock:
-        """Fused transform + per-unit KPI rollup in ONE dispatch: the
-        block's facts/found plus ``rollup_host()`` ==
+        """Fused steelworks transform + per-unit KPI rollup in ONE
+        dispatch: the block's facts/found plus ``rollup_host()`` ==
         ``segment_reduce(facts[found], n_units)`` (parity-tested like the
         other ops). The hot path's replacement for the separate
         transform-then-rollup round trips."""
-        return self.transform_block(prod, equipment, quality,
-                                    join_depth=join_depth, n_units=n_units)
+        return self.transform_block(prod, *caches, join_depth=join_depth,
+                                    n_units=n_units)
 
     def segment_reduce(self, facts: np.ndarray, n_units: int) -> np.ndarray:
         """Per-equipment KPI rollup of a fact block: sums
@@ -644,7 +651,7 @@ class ComputeBackend:
         bucket = max(floor, 1 << (n - 1).bit_length())
         if bucket == n:
             return prod.copy() if mutable else prod
-        padrow = np.full((bucket - n, prod.shape[1]), -1.0, np.float32)
+        padrow = np.full((bucket - n, prod.shape[1]), -1, prod.dtype)
         return np.concatenate([prod, padrow])
 
 
@@ -696,7 +703,7 @@ def _hash_probe_np(query_keys, keys_tbl, vals_tbl, txn_tbl):
     n = len(q)
     done = np.zeros(n, bool)
     found = np.zeros(n, bool)
-    val = np.zeros((n, vals_tbl.shape[1]), np.float32)
+    val = np.zeros((n, vals_tbl.shape[1]), vals_tbl.dtype)
     txn = np.zeros(n, txn_tbl.dtype)
     for p in range(MAX_PROBES):
         cand = (h + p) % n_slots
@@ -711,6 +718,37 @@ def _hash_probe_np(query_keys, keys_tbl, vals_tbl, txn_tbl):
         if done.all():
             break
     return val, found, txn
+
+
+def _lookup_asof_np(query_keys, query_times, keys_tbl, vals_tbl,
+                    asof_lane: int):
+    """Host twin of ``cache.lookup_asof``: per key, the version with the
+    latest EffectiveDate not after its query time. Returns (values [n, W],
+    found [n] bool)."""
+    from repro.core.cache import MAX_PROBES, hash32_np
+    keys_tbl = np.asarray(keys_tbl)
+    vals_tbl = np.asarray(vals_tbl)
+    n_slots = keys_tbl.shape[0]
+    q = (np.asarray(query_keys).astype(np.int64)
+         & 0xFFFFFFFF).astype(np.int32)
+    t = np.asarray(query_times).astype(np.int64)
+    h = (hash32_np(q) % np.uint32(n_slots)).astype(np.int64)
+    done = np.zeros(len(q), bool)
+    idx = np.full(len(q), -1, np.int64)
+    best = np.zeros(len(q), np.int64)
+    for p in range(MAX_PROBES):
+        cand = (h + p) % n_slots
+        k = keys_tbl[cand]
+        eff = vals_tbl[cand, asof_lane].astype(np.int64)
+        better = (k == q) & (eff <= t) & ((idx < 0) | (eff > best)) & ~done
+        idx = np.where(better, cand, idx)
+        best = np.where(better, eff, best)
+        done |= k == -1
+        if done.all():
+            break
+    found = idx >= 0
+    val = np.where(found[:, None], vals_tbl[np.maximum(idx, 0)], 0)
+    return val.astype(vals_tbl.dtype), found
 
 
 def _segment_reduce_np(facts: np.ndarray, n_units: int) -> np.ndarray:
@@ -742,8 +780,17 @@ class NumpyBackend(ComputeBackend):
         self.op_dispatches += 1
         return _hash_probe_np(query_keys, keys_tbl, vals_tbl, txn_tbl)
 
-    def transform_block(self, prod, equipment, quality, *, join_depth=1,
-                        n_units=None):
+    def transform_block(self, prod, *caches, schema="steelworks",
+                        join_depth=1, n_units=None):
+        if schema == "tpcdi-dimtrade":
+            from repro.core.dimtrade import dimtrade_np
+            acct, sec = caches
+            rows, found = dimtrade_np(prod, (acct.keys, acct.values),
+                                      (sec.keys, sec.values, sec.txn),
+                                      acct.asof_lane)
+            self.op_dispatches += 1
+            return FactBlock(self, rows, found, len(rows))
+        equipment, quality = caches
         prod = np.asarray(prod, np.float32)
         eq_state = (equipment.keys, equipment.values, equipment.txn)
         q_state = (quality.keys, quality.values, quality.txn)
@@ -846,11 +893,23 @@ class JaxBackend(ComputeBackend):
         self.host_syncs += 1
         return np.asarray(vals), np.asarray(found), np.asarray(txn)
 
-    def transform_block(self, prod, equipment, quality, *, join_depth=1,
-                        n_units=None):
+    def transform_block(self, prod, *caches, schema="steelworks",
+                        join_depth=1, n_units=None):
         import jax.numpy as jnp
+        if schema == "tpcdi-dimtrade":
+            from repro.core.dimtrade import dimtrade_transform_kernel
+            acct, sec = caches
+            prod = np.asarray(prod, np.int32)
+            ak, av, at = acct.device_state()
+            sk, sv, st = sec.device_state()
+            rows, found = dimtrade_transform_kernel(
+                jnp.asarray(self._pad_bucket(prod, floor=128)), ak, av, at,
+                sk, sv, st, asof_lane=acct.asof_lane)
+            self.op_dispatches += 1
+            return FactBlock(self, rows, found, len(prod))
         from repro.core.transformer import (transform_kernel,
                                             transform_rollup_kernel)
+        equipment, quality = caches
         prod = np.asarray(prod, np.float32)
         n = len(prod)
         padded = jnp.asarray(self._pad_bucket(prod, floor=128))
@@ -1225,10 +1284,14 @@ class PallasBackend(ComputeBackend):
         self.host_syncs += 1
         return np.asarray(vals), np.asarray(found), np.asarray(txn)
 
-    def transform_block(self, prod, equipment, quality, *, join_depth=1,
-                        n_units=None):
+    def transform_block(self, prod, *caches, schema="steelworks",
+                        join_depth=1, n_units=None):
         import jax.numpy as jnp
         from repro.kernels.hash_join.ops import hash_join
+        if schema != "steelworks":
+            raise NotImplementedError(
+                f"the pallas backend has no {schema!r} transform")
+        equipment, quality = caches
         from repro.kernels.segment_kpi.ops import segment_kpi
         prod = np.asarray(prod, np.float32)
         n = len(prod)

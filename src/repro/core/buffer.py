@@ -25,9 +25,20 @@ from repro.core.records import RecordBatch
 
 
 class OperationalMessageBuffer:
-    def __init__(self, capacity: int):
+    """``ordered`` keeps the held records in log (LSN) order, so that a
+    retry sweep, which takes the oldest ready records first, never takes a
+    later record of a row key without the earlier ones, and a record put
+    back after a retry goes in front of the later records of its key (a
+    keyed target applies its records in log order per key)."""
+
+    def __init__(self, capacity: int, ordered: bool = False):
         self.capacity = capacity
+        self.ordered = ordered
         self._batch: RecordBatch = RecordBatch.empty()
+        # per held record: owed a retry (held against caches that may
+        # predate its master rows, or one of them arrived since; see
+        # ``pop_ready``'s ``keys``)
+        self._fresh = np.zeros(0, bool)
         self.dropped = 0
         self.total_buffered = 0
         self.total_retried = 0
@@ -35,34 +46,60 @@ class OperationalMessageBuffer:
     def __len__(self) -> int:
         return len(self._batch)
 
-    def push(self, late: RecordBatch) -> None:
+    def row_keys(self) -> np.ndarray:
+        """Row keys of the held records, in hold order."""
+        return self._batch.row_key
+
+    def peek(self) -> RecordBatch:
+        """The held records, in hold order (not removed)."""
+        return self._batch
+
+    def push(self, late: RecordBatch, fresh: bool = True) -> None:
+        """Hold ``late``. ``fresh``: the records were transformed against
+        a cache snapshot that may predate master rows already arrived (a
+        retry sweep's records, tried just now, are not)."""
         if not len(late):
             return
         self.total_buffered += len(late)
         merged = RecordBatch.concat([self._batch, late])
+        flags = np.concatenate([self._fresh, np.full(len(late), fresh)])
+        if self.ordered:
+            order = np.argsort(merged.lsn, kind="stable")
+            merged, flags = merged.take(order), flags[order]
         if len(merged) > self.capacity:
             # drop oldest beyond capacity (recorded; tests assert zero drops
             # under the paper's workloads)
             self.dropped += len(merged) - self.capacity
-            merged = merged.take(np.arange(len(merged) - self.capacity,
-                                           len(merged)))
-        self._batch = merged
+            keep = np.arange(len(merged) - self.capacity, len(merged))
+            merged, flags = merged.take(keep), flags[keep]
+        self._batch, self._fresh = merged, flags
 
-    def pop_ready(self, watermark: int,
-                  limit: Optional[int] = None) -> RecordBatch:
+    def pop_ready(self, watermark: int, limit: Optional[int] = None,
+                  keys: Optional[np.ndarray] = None) -> RecordBatch:
         """Remove and return records eligible for retry (txn_time <=
         watermark). ``limit`` bounds one retry sweep (oldest-first), so a
         mass-late cold start is drained in micro-batches instead of one
-        giant dispatch."""
+        giant dispatch. ``keys`` (sorted business keys whose master rows
+        arrived since the last sweep) narrows the sweep to the records
+        those rows can complete, and the fresh ones; a record it leaves
+        out could only miss again."""
         if not len(self._batch):
             return RecordBatch.empty()
         ready_mask = self._batch.txn_time <= watermark
+        if keys is not None:
+            # a record whose key arrived stays owed a retry until one
+            # takes it (the watermark may hold it back past this sweep)
+            from repro.core.partitioning import isin_sorted
+            self._fresh |= isin_sorted(keys, self._batch.business_key)
+            ready_mask &= self._fresh
         if limit is not None and ready_mask.sum() > limit:
             keep_off = np.nonzero(ready_mask)[0][limit:]
             ready_mask = ready_mask.copy()
             ready_mask[keep_off] = False
+            self._fresh[keep_off] = True      # still owed a retry
         ready = self._batch.filter(ready_mask)
         self._batch = self._batch.filter(~ready_mask)
+        self._fresh = self._fresh[~ready_mask]
         self.total_retried += len(ready)
         return ready
 
@@ -71,6 +108,7 @@ class OperationalMessageBuffer:
         worker's replicated buffer is adopted by a survivor)."""
         out = self._batch
         self._batch = RecordBatch.empty()
+        self._fresh = np.zeros(0, bool)
         return out
 
     # ---------------------------------------------------------- durability
@@ -78,10 +116,12 @@ class OperationalMessageBuffer:
         return {"batch": self._batch.as_dict(), "dropped": self.dropped}
 
     @staticmethod
-    def restore(state: dict, capacity: int) -> "OperationalMessageBuffer":
-        buf = OperationalMessageBuffer(capacity)
+    def restore(state: dict, capacity: int,
+                ordered: bool = False) -> "OperationalMessageBuffer":
+        buf = OperationalMessageBuffer(capacity, ordered)
         buf._batch = RecordBatch(**{k: np.asarray(v)
                                     for k, v in state["batch"].items()})
+        buf._fresh = np.ones(len(buf._batch), bool)
         buf.dropped = state.get("dropped", 0)
         return buf
 

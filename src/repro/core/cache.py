@@ -4,9 +4,13 @@ The paper gives each Spark worker an embedded H2 instance holding only the
 master rows for its assigned business keys. On TPU the worker-local store is
 a device-resident open-addressing hash table:
 
-  keys   : i64 [n_slots]   (-1 = empty)   — the JOIN key of the table
-  values : f32 [n_slots, W]               — master row payload
+  keys   : i32 [n_slots]   (-1 = empty)   — the JOIN key of the table
+  values : f32|i32 [n_slots, W]           — master row payload (the
+                                            table's lanes and width)
   txn    : i64 [n_slots]                  — row transaction time (watermark)
+
+``VersionedTable`` keeps every version of a type-2 dimension (one slot per
+(key, EffectiveDate)) and answers point-in-time probes (``lookup_asof``).
 
 Slot assignment happens host-side at update time (updates are rare next to
 lookups); the hot path — the probe inside the Data Transformer — goes
@@ -22,6 +26,7 @@ initialization overhead.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Set, Tuple
 
 import jax
@@ -57,13 +62,15 @@ def hash32_jnp(keys: jax.Array) -> jax.Array:
 
 
 class InMemoryTable:
+    asof_lane = None             # VersionedTable: the EffectiveDate lane
+
     def __init__(self, n_slots: int, width: int = PAYLOAD_WIDTH,
-                 backend=None):
+                 backend=None, dtype=np.float32):
         self.n_slots = n_slots
         self.width = width
         self._backend = backend          # name/instance; resolved lazily
         self.keys = np.full(n_slots, -1, np.int32)
-        self.values = np.zeros((n_slots, width), np.float32)
+        self.values = np.zeros((n_slots, width), dtype)
         self.txn = np.zeros(n_slots, np.int64)
         self.watermark = 0           # latest master txn_time seen
         self.n_rows = 0
@@ -95,7 +102,7 @@ class InMemoryTable:
         old_keys, old_vals, old_txn = self.keys, self.values, self.txn
         self.n_slots *= 2
         self.keys = np.full(self.n_slots, -1, np.int32)
-        self.values = np.zeros((self.n_slots, self.width), np.float32)
+        self.values = np.zeros((self.n_slots, self.width), old_vals.dtype)
         self.txn = np.zeros(self.n_slots, np.int64)
         self.n_rows = 0
         live = np.nonzero(old_keys != -1)[0]
@@ -125,7 +132,7 @@ class InMemoryTable:
             return
         keys = np.asarray(keys, np.int64)
         txn_times = np.asarray(txn_times, np.int64)
-        payloads = np.asarray(payloads, np.float32)
+        payloads = np.asarray(payloads, self.values.dtype)
         # watermark advances over ALL arriving rows, stale or not (same as
         # the per-row loop: it tracked skipped rows' txn times too)
         self.watermark = max(self.watermark, int(txn_times.max()))
@@ -300,11 +307,13 @@ class InMemoryTable:
                 self.upload_bytes = sum(
                     a.nbytes for a, b in zip(state, before) if a is not b)
                 self._snap = CacheSnapshot(None, None, None, self.watermark,
-                                           state, backend=self._backend)
+                                           state, backend=self._backend,
+                                           asof_lane=self.asof_lane)
             else:
                 self._snap = CacheSnapshot(
                     self.keys.copy(), self.values.copy(), self.txn.copy(),
-                    self.watermark, None, backend=self._backend)
+                    self.watermark, None, backend=self._backend,
+                    asof_lane=self.asof_lane)
             self._snap_version = (self.version, device)
         return self._snap
 
@@ -313,9 +322,12 @@ class CacheSnapshot:
     """Frozen view of an ``InMemoryTable`` (see ``snapshot_view``): exactly
     the read surface the compute backends touch, nothing else."""
 
-    __slots__ = ("keys", "values", "txn", "watermark", "_device", "_backend")
+    __slots__ = ("keys", "values", "txn", "watermark", "_device", "_backend",
+                 "asof_lane")
 
-    def __init__(self, keys, values, txn, watermark, device, backend=None):
+    def __init__(self, keys, values, txn, watermark, device, backend=None,
+                 asof_lane=None):
+        self.asof_lane = asof_lane
         self.keys = keys
         self.values = values
         self.txn = txn
@@ -377,3 +389,199 @@ def lookup_ref(query_keys: jax.Array, keys_tbl: jax.Array,
     val = jnp.where(found[:, None], vals_tbl[safe], 0)
     txn = jnp.where(found, txn_tbl[safe], 0)
     return val, found, txn
+
+
+SCATTER_MAX = 1 << 16     # slots one in-place device update writes
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scatter_slots(state, idx, keys, vals, txn):
+    """Write rows into a device mirror's slots ``idx``, in place."""
+    k, v, t = state
+    return k.at[idx].set(keys), v.at[idx].set(vals), t.at[idx].set(txn)
+
+
+class VersionedTable(InMemoryTable):
+    """Cache of a type-2 slowly changing dimension: one slot per version,
+    keyed by the dimension's business key and told apart by the version's
+    EffectiveDate (payload lane ``asof_lane``). A key's versions all lie
+    inside its ``MAX_PROBES`` probe window, so a point-in-time probe
+    (``lookup_asof``) sees every version a key has.
+
+    A version that cannot be placed because its key's window is already
+    filled with that key's own versions is an *overflow*: counted in
+    ``overflows``, and the key's oldest version is dropped (or the new one,
+    when it is the oldest). A probe whose time falls in a dropped version's
+    interval then finds nothing and waits in the late buffer; it never
+    finds a wrong version, since every version it can see is either the
+    right one or newer than its time. A window crowded by other keys is no
+    overflow: the table grows, as ``InMemoryTable`` does."""
+
+    def __init__(self, n_slots: int, width: int, asof_lane: int,
+                 backend=None, dtype=np.int32):
+        super().__init__(n_slots, width, backend, dtype)
+        self.asof_lane = asof_lane
+        self.overflows = 0
+        self._changed: list = []     # slots written since the device sync
+        self._sent = 0               # bytes the last device sync sent
+
+    def upsert(self, keys: np.ndarray, payloads: np.ndarray,
+               txn_times: np.ndarray) -> None:
+        """Insert versions; the same (key, EffectiveDate) again overwrites
+        its slot, last writer by transaction time winning (arrival order
+        breaking ties), as ``InMemoryTable.upsert`` does per key."""
+        n = len(keys)
+        if n == 0:
+            return
+        keys = np.asarray(keys, np.int64)
+        txn_times = np.asarray(txn_times, np.int64)
+        payloads = np.asarray(payloads, self.values.dtype)
+        self.watermark = max(self.watermark, int(txn_times.max()))
+        eff = payloads[:, self.asof_lane].astype(np.int64)
+        order = np.lexsort((np.arange(n), txn_times, eff, keys))
+        ko, eo = keys[order], eff[order]
+        win = order[np.nonzero(np.append(
+            (ko[1:] != ko[:-1]) | (eo[1:] != eo[:-1]), True))[0]]
+        self._insert((keys[win] & 0xFFFFFFFF).astype(np.int32), eff[win],
+                     payloads[win], txn_times[win])
+        self.version += 1
+
+    def device_state(self) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """Device mirror of (keys, values, txn). A dimension's versions
+        keep arriving, so after the first upload only the slots written
+        since the last call go over, scattered into the mirror IN PLACE
+        (its buffers are donated): the old mirror arrays are invalid once
+        this returns, so a caller that uses a snapshot's arrays must do so
+        under the same lock as this call (the concurrent runtime launches
+        a worker's transform under its cache lock when it has a versioned
+        cache). A rewrite of the whole table (growth, a reset, a
+        migration) uploads it whole."""
+        if self._device is None or self._dirty:
+            self._device = tuple(jnp.asarray(np.array(a)) for a in
+                                 (self.keys, self.values, self.txn))
+            self._dirty.clear()
+            self._changed = []
+            self._sent = sum(a.nbytes for a in self._device)
+        elif self._changed:
+            idx = np.unique(np.concatenate(self._changed))
+            self._changed = []
+            for lo in range(0, len(idx), SCATTER_MAX):
+                part = idx[lo:lo + SCATTER_MAX]
+                pad = np.resize(part, 1 << (len(part) - 1).bit_length())
+                self._device = _scatter_slots(
+                    self._device, pad, self.keys[pad], self.values[pad],
+                    self.txn[pad].astype(np.int32))
+                self._sent += len(pad) * (8 + self.values.itemsize
+                                          * (self.width + 1))
+        return self._device
+
+    def snapshot_view(self, device: bool) -> "CacheSnapshot":
+        self._sent = 0
+        snap = super().snapshot_view(device)
+        if device:
+            self.upload_bytes = self._sent
+        return snap
+
+    def compile_updates(self) -> None:
+        """Compile the in-place slot update at every size it can take
+        (powers of two up to ``SCATTER_MAX``), rewriting slots with their
+        own values."""
+        self.device_state()
+        size = 1
+        while size <= SCATTER_MAX:
+            idx = np.arange(size) % self.n_slots
+            self._device = _scatter_slots(
+                self._device, idx, self.keys[idx], self.values[idx],
+                self.txn[idx].astype(np.int32))
+            size *= 2
+
+    def _insert(self, key32, eff, vals, txns) -> None:
+        lane = self.asof_lane
+        h = (hash32_np(key32) % np.uint32(self.n_slots)).astype(np.int64)
+        pending = np.arange(len(key32))
+        for p in range(MAX_PROBES):
+            if not len(pending):
+                break
+            cand = (h[pending] + p) % self.n_slots
+            slot_keys = self.keys[cand]
+            same = ((slot_keys == key32[pending])
+                    & (self.values[cand, lane] == eff[pending]))
+            upd = same & (txns[pending] >= self.txn[cand])
+            s, i = cand[upd], pending[upd]
+            self.values[s], self.txn[s] = vals[i], txns[i]
+            self._changed.append(s)
+            empty = np.nonzero(slot_keys == -1)[0]
+            claimed = np.zeros(len(pending), bool)
+            if len(empty):
+                _, first = np.unique(cand[empty], return_index=True)
+                s, i = cand[empty[first]], pending[empty[first]]
+                self.keys[s], self.values[s], self.txn[s] = (
+                    key32[i], vals[i], txns[i])
+                self._changed.append(s)
+                self.n_rows += len(i)
+                claimed[empty[first]] = True
+            pending = pending[~(same | claimed)]
+        crowded = []
+        for i in pending:            # rare: the key's window is full
+            win = (h[i] + np.arange(MAX_PROBES)) % self.n_slots
+            mine = win[self.keys[win] == key32[i]]
+            if len(mine) < MAX_PROBES:
+                crowded.append(i)
+                continue
+            self.overflows += 1
+            oldest = mine[np.argmin(self.values[mine, lane])]
+            if self.values[oldest, lane] < eff[i]:
+                self.values[oldest], self.txn[oldest] = vals[i], txns[i]
+                self._changed.append(np.array([oldest]))
+        if crowded:
+            idx = np.asarray(crowded)
+            self._grow()
+            self._insert(key32[idx], eff[idx], vals[idx], txns[idx])
+
+    def _grow(self) -> None:
+        live = np.nonzero(self.keys != -1)[0]
+        keys, vals, txns = self.keys[live], self.values[live], self.txn[live]
+        self.n_slots *= 2
+        self.keys = np.full(self.n_slots, -1, np.int32)
+        self.values = np.zeros((self.n_slots, self.width), vals.dtype)
+        self.txn = np.zeros(self.n_slots, np.int64)
+        self.n_rows = 0
+        self._insert(keys, vals[:, self.asof_lane].astype(np.int64), vals,
+                     txns)
+        self._device = None
+        self._dirty = {"keys", "values", "txn"}
+
+
+def lookup_asof(query_keys: jax.Array, query_times: jax.Array,
+                keys_tbl: jax.Array, vals_tbl: jax.Array, asof_lane: int
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Point-in-time probe of a ``VersionedTable`` (traced inside the
+    transform kernels): for each key, the version with the latest
+    EffectiveDate (lane ``asof_lane``) not after its query time. The scan
+    reads the key lane and that one value lane of each window slot and
+    stops at an empty slot; the winning row is gathered once. Returns
+    (values [n, W], found [n] bool)."""
+    n_slots = keys_tbl.shape[0]
+    q = query_keys.astype(jnp.int32)
+    t = query_times.astype(jnp.int32)
+    h = (hash32_jnp(q) % jnp.uint32(n_slots)).astype(jnp.int32)
+
+    def probe(carry, p):
+        done, idx, best = carry
+        cand = (h + p) % n_slots
+        k = keys_tbl[cand]
+        # gathered element by element: slicing the lane out of the whole
+        # table first would read every slot's row on each call
+        eff = vals_tbl[cand, asof_lane]
+        better = (k == q) & (eff <= t) & ((idx < 0) | (eff > best)) & ~done
+        idx = jnp.where(better, cand, idx)
+        best = jnp.where(better, eff, best)
+        return (done | (k == -1), idx, best), None
+
+    n = q.shape[0]
+    init = (jnp.zeros(n, bool), jnp.full(n, -1, jnp.int32),
+            jnp.zeros(n, vals_tbl.dtype))
+    (_, idx, _), _ = jax.lax.scan(probe, init, jnp.arange(MAX_PROBES))
+    found = idx >= 0
+    val = jnp.where(found[:, None], vals_tbl[jnp.maximum(idx, 0)], 0)
+    return val, found

@@ -11,6 +11,7 @@ The Message Producer partitions extracted records per the table nature
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 from typing import Dict, List, Optional
@@ -31,6 +32,7 @@ class Listener:
         self.log = log
         self.queue = queue
         self.topic = topic
+        self.width = table.width
         self.offset = 0              # LSN position in the shared log
         self.records_extracted = 0
         self.records_scanned = 0
@@ -44,6 +46,10 @@ class Listener:
         self.records_scanned += scanned
         if len(batch):
             mine = batch.filter(batch.table_id == self.table_id)
+            if mine.payload.shape[1] > self.width:
+                # the log's rows are its widest table's: keep own lanes
+                mine = dataclasses.replace(
+                    mine, payload=mine.payload[:, :self.width])
             if len(mine):
                 self.queue.publish(self.topic, mine)
                 self.records_extracted += len(mine)

@@ -43,6 +43,129 @@ class WarehouseView:
     rows: int
 
 
+class KeyedTable:
+    """A dimension the warehouse updates in place: int rows keyed by one
+    column, with a host hash index (open addressing over the key, the
+    cache's 32-bit hash, grown at half load) from key to row. ``applied``
+    counts the CDC records applied to each row, which is what an
+    exactly-once check reads."""
+
+    def __init__(self, key_col: int, keep: Tuple[int, ...] = (),
+                 capacity: int = 1024):
+        self.key_col = key_col
+        self.keep = np.asarray(keep, np.int64)
+        self.rows = np.zeros((capacity, 0), np.int32)   # width: first rows
+        self.applied = np.zeros(capacity, np.int32)
+        self.n = 0
+        self._keys = np.full(2 * capacity, -1, np.int64)
+        self._pos = np.zeros(2 * capacity, np.int64)
+
+    def _probe(self, keys: np.ndarray):
+        """(row position or -1, index slot or -1) per key; a key's slot is
+        where it lives or the first empty slot of its chain."""
+        from repro.core.cache import hash32_np
+        mask = len(self._keys) - 1
+        h = hash32_np(keys).astype(np.int64)
+        pos = np.full(len(keys), -1, np.int64)
+        slot = np.full(len(keys), -1, np.int64)
+        pending = np.arange(len(keys))
+        p = 0
+        while len(pending):
+            cand = (h[pending] + p) & mask
+            k = self._keys[cand]
+            hit, empty = k == keys[pending], k == -1
+            pos[pending[hit]] = self._pos[cand[hit]]
+            slot[pending[hit | empty]] = cand[hit | empty]
+            pending = pending[~(hit | empty)]
+            p += 1
+        return pos, slot
+
+    def _index(self, keys: np.ndarray, at: np.ndarray) -> None:
+        """Index new distinct ``keys`` at row positions ``at``."""
+        pending = np.arange(len(keys))
+        while len(pending):
+            _, slot = self._probe(keys[pending])
+            _, first = np.unique(slot, return_index=True)
+            won = pending[first]
+            self._keys[slot[first]] = keys[won]
+            self._pos[slot[first]] = at[won]
+            pending = np.setdiff1d(pending, won, assume_unique=True)
+
+    def _reserve(self, extra: int) -> None:
+        need = self.n + extra
+        if need > len(self.rows):
+            cap = max(need, 2 * len(self.rows))
+            self.rows = np.concatenate(
+                [self.rows, np.zeros((cap - len(self.rows),
+                                      self.rows.shape[1]), np.int32)])
+            self.applied = np.concatenate(
+                [self.applied, np.zeros(cap - len(self.applied), np.int32)])
+        if 2 * need > len(self._keys):
+            size = len(self._keys)
+            while 2 * need > size:
+                size *= 2
+            self._keys = np.full(size, -1, np.int64)
+            self._pos = np.zeros(size, np.int64)
+            self._index(self.rows[:self.n, self.key_col].astype(np.int64),
+                        np.arange(self.n))
+
+    def upsert(self, rows: np.ndarray) -> int:
+        """Apply ``rows`` in order: a new key inserts its row; a known key
+        is overwritten, each ``keep`` column keeping its stored value where
+        the new row carries NULL (-1). A key met twice in one call is
+        applied twice, in order (rounds of first occurrences). Returns how
+        many keys were new."""
+        rows = np.asarray(rows, np.int32)
+        if not self.n and self.rows.shape[1] != rows.shape[1]:
+            self.rows = np.zeros((len(self.rows), rows.shape[1]), np.int32)
+        keys = rows[:, self.key_col].astype(np.int64)
+        order = np.lexsort((np.arange(len(keys)), keys))
+        ks = keys[order]
+        start = np.r_[0, np.nonzero(ks[1:] != ks[:-1])[0] + 1]
+        rank = np.empty(len(keys), np.int64)
+        rank[order] = np.arange(len(keys)) - np.repeat(
+            start, np.diff(np.r_[start, len(keys)]))
+        inserted = 0
+        for r in range(int(rank.max()) + 1 if len(rank) else 0):
+            idx = np.nonzero(rank == r)[0]
+            self._reserve(len(idx))
+            pos, _ = self._probe(keys[idx])
+            new = pos < 0
+            at = self.n + np.arange(int(new.sum()))
+            self._index(keys[idx[new]], at)
+            self.rows[at] = rows[idx[new]]
+            self.applied[at] = 1
+            self.n += len(at)
+            inserted += len(at)
+            old = pos[~new]
+            if len(old):
+                upd = rows[idx[~new]]
+                if len(self.keep):
+                    kept = upd[:, self.keep]
+                    upd[:, self.keep] = np.where(
+                        kept == -1, self.rows[old][:, self.keep], kept)
+                self.rows[old] = upd
+                self.applied[old] += 1
+        return inserted
+
+    def table(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(rows, applied counts), in insertion order (copies)."""
+        return self.rows[:self.n].copy(), self.applied[:self.n].copy()
+
+    def restore(self, rows: np.ndarray, applied: np.ndarray) -> None:
+        """Replace the contents with ``table()``'s output (recovery)."""
+        self.rows = np.array(rows, np.int32)
+        self.applied = np.array(applied, np.int32)
+        self.n = len(self.rows)
+        size = len(self._keys)
+        while size < 2 * self.n:
+            size *= 2
+        self._keys = np.full(size, -1, np.int64)
+        self._pos = np.zeros(size, np.int64)
+        self._index(self.rows[:, self.key_col].astype(np.int64),
+                    np.arange(self.n))
+
+
 class StarSchemaWarehouse:
     """Loads are thread-safe: the concurrent runtime's load stages append
     from one thread per worker, so the chunk log, commit sequence and
@@ -69,6 +192,32 @@ class StarSchemaWarehouse:
         # per-partition loops, the record-at-a-time baseline — gap it
         self._kpi_running: Optional[np.ndarray] = None
         self._kpi_gap_rows = 0
+        self.keyed: Optional[KeyedTable] = None   # see ``keyed_target``
+
+    def keyed_target(self, key_col: int,
+                     keep: Tuple[int, ...] = ()) -> KeyedTable:
+        """Make the warehouse's target a ``KeyedTable`` that ``upsert``
+        updates in place (a dimension), in place of the appended fact
+        chunk log."""
+        with self._lock:
+            self.keyed = KeyedTable(key_col, keep)
+            return self.keyed
+
+    def upsert(self, rows: np.ndarray) -> int:
+        """Apply one block of target rows to the keyed table, in order,
+        under the load lock (so concurrent workers' blocks never
+        interleave). Counts every row as loaded. Returns how many rows
+        updated a key already present. No view engine reads a keyed
+        target: folding updates would need retraction. The chunk log
+        (``commit_seq``) does not hold a keyed target; ``export_state``
+        captures it whole."""
+        if self._serving is not None:
+            raise ValueError("a keyed target has no serving views")
+        with self._lock:
+            inserted = self.keyed.upsert(rows)
+            self.rows_loaded += len(rows)
+            self.load_calls += 1
+        return len(rows) - inserted
 
     # ------------------------------------------------------------ serving hook
     def attach_serving(self, engine, replay_from: int = 0):
@@ -178,6 +327,9 @@ class StarSchemaWarehouse:
                 "kpi_running": (None if self._kpi_running is None
                                 else self._kpi_running.copy()),
                 "kpi_gap_rows": int(self._kpi_gap_rows),
+                # a keyed target is captured whole at every step
+                "keyed": (None if self.keyed is None else
+                          dict(zip(("rows", "applied"), self.keyed.table()))),
             }
 
     def restore_state(self, state: Dict) -> None:
@@ -200,6 +352,9 @@ class StarSchemaWarehouse:
             self._kpi_running = (None if state["kpi_running"] is None
                                  else np.asarray(state["kpi_running"]))
             self._kpi_gap_rows = int(state["kpi_gap_rows"])
+            if state.get("keyed") is not None:
+                self.keyed.restore(state["keyed"]["rows"],
+                                   state["keyed"]["applied"])
 
     def _commit(self, block: np.ndarray,
                 event_times: Optional[np.ndarray],

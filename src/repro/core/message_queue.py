@@ -432,12 +432,15 @@ class MessageQueue:
         return batch
 
     def consume_many(self, group: str, topic: str, partitions,
-                     max_records_per_partition: Optional[int] = None
+                     max_records_per_partition: Optional[int] = None,
+                     until_txn: Optional[int] = None
                      ) -> Tuple[RecordBatch, Dict[int, int]]:
         """Coalesce reads across ``partitions`` into ONE columnar batch —
         the Stream Processor's single-dispatch micro-batch. Returns
         (batch, {partition: records_read}); offsets still advance per
-        partition via ``commit`` so rebalance handoff stays exact."""
+        partition via ``commit`` so rebalance handoff stays exact.
+        ``until_txn`` stops each partition's read before its first record
+        with a later transaction time (log order up to that time)."""
         out: List[RecordBatch] = []
         counts: Dict[int, int] = {}
         t = self.topics[topic]
@@ -447,6 +450,10 @@ class MessageQueue:
             if off >= t.partitions[p].length:     # drained: skip the read
                 continue
             b = t.partitions[p].read(off, max_records_per_partition)
+            if until_txn is not None and len(b):
+                over = np.flatnonzero(b.txn_time > until_txn)
+                if len(over):
+                    b = b.slice(0, int(over[0]))
             if len(b):
                 out.append(b)
                 counts[p] = len(b)

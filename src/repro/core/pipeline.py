@@ -14,10 +14,10 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.configs.dod_etl import ETLConfig
+from repro.configs.dod_etl import ETLConfig, TableConfig
 from repro.core.backend import get_backend
 from repro.core.buffer import DeadLetterBuffer, OperationalMessageBuffer
-from repro.core.cache import InMemoryTable
+from repro.core.cache import InMemoryTable, VersionedTable
 from repro.core.cdc import SourceDatabase
 from repro.core.listener import ChangeTracker
 from repro.core.message_queue import MessageQueue
@@ -95,6 +95,15 @@ def migration_summary(epoch: int, moved_key_fraction: float,
             "dump_s": round(stats.dump_s, 6)}
 
 
+def _new_cache(t: TableConfig, n_slots: int, backend) -> InMemoryTable:
+    """The cache of master table ``t``: versioned for a type-2 table."""
+    dtype = np.int32 if t.lanes == "int32" else np.float32
+    if t.asof_lane is not None:
+        return VersionedTable(n_slots, t.width, t.asof_lane, backend=backend,
+                              dtype=dtype)
+    return InMemoryTable(n_slots, t.width, backend=backend, dtype=dtype)
+
+
 class StreamProcessorWorker:
     """One Stream Processor node: assigned business-key partitions, local
     in-memory caches (filtered by assigned keys), transformer + loader.
@@ -120,21 +129,41 @@ class StreamProcessorWorker:
         # old epoch keep finding their master rows). None = legacy static.
         self._routing_topics: Optional[List[str]] = None
         self._pending_tables: tuple = ()   # tables acked but not yet switched
-        self.equipment = InMemoryTable(cfg.cache_slots, cfg.cache_row_width,
-                                       backend=self.backend)
-        self.quality = InMemoryTable(cfg.cache_slots, cfg.cache_row_width,
-                                     backend=self.backend)
-        self.buffer = OperationalMessageBuffer(cfg.buffer_capacity)
+        # one cache per cached master table, in the configuration's order
+        # (the order the schema's transform takes them); the loops over
+        # them are built here once, not per batch
+        self.masters: Tuple[Tuple[TableConfig, InMemoryTable], ...] = tuple(
+            (t, _new_cache(t, t.slots or cfg.cache_slots, self.backend))
+            for t in cfg.cached_tables)
+        self.caches: Tuple[InMemoryTable, ...] = tuple(
+            c for _, c in self.masters)
+        self._master_topics = {t.name: f"topic.{t.name}"
+                               for t, _ in self.masters}
+        self._table_of_topic = {v: t for (t, _), v in zip(
+            self.masters, self._master_topics.values())}
+        # newest operational transaction time fetched: the bound of the
+        # master pump when masters are applied in log order
+        self._log_order = cfg.masters_in_log_order
+        self.horizon = -1
+        # business keys of the partitioned master rows pumped since the
+        # last retry sweep (``take_arrivals``); None: a replicated table
+        # changed or the caches were rewritten, so any held record may join
+        self._arrived: Optional[List[np.ndarray]] = None
+        # a keyed target: an update must not overtake its held insert
+        self.keyed = cfg.target_key is not None
+        self.buffer = OperationalMessageBuffer(cfg.buffer_capacity,
+                                               ordered=self.keyed)
         # poison-record quarantine: records whose transform deterministically
         # raises are parked here (offsets committed) instead of crash-looping
         self.dead_letter = DeadLetterBuffer()
-        # n_units wires the fused transform_and_rollup: every transform
+        # n_units wires the steelworks fused transform_and_rollup: every
         # dispatch also carries the per-unit KPI aggregate (equipment ids
         # ARE the business keys), feeding warehouse.kpi_running in O(1)
-        self.transformer = DataTransformer(self.equipment, self.quality,
-                                           self.buffer, join_depth,
-                                           backend=self.backend,
-                                           n_units=cfg.n_business_keys)
+        self.transformer = DataTransformer(
+            self.caches, self.buffer, join_depth, backend=self.backend,
+            n_units=(cfg.n_business_keys if cfg.schema == "steelworks"
+                     else None),
+            schema=cfg.schema)
         self.metrics = StageMetrics()
         self.group = f"sp.{name}"
         # fault seams (tests): the pipeline points this at its injector;
@@ -157,10 +186,24 @@ class StreamProcessorWorker:
         self._c_hits = shard.counter("worker.cache_hits")
         self._c_misses = shard.counter("worker.cache_misses")
         self._c_dead = shard.counter("worker.dead_lettered")
+        self._c_held = shard.counter("worker.records_held")
+        self._c_overflow = shard.counter("worker.asof_overflows")
+        self._c_lifts = shard.counter("worker.horizon_lifts")
         shard.gauge_fn("buffer_occupancy", lambda: len(self.buffer))
         shard.gauge_fn("dead_letter_occupancy", lambda: len(self.dead_letter))
-        shard.gauge_fn("cache_rows",
-                       lambda: self.equipment.n_rows + self.quality.n_rows)
+        shard.gauge_fn("cache_rows", self.cache_rows)
+
+    # steelworks' two caches by role (the configuration's first two)
+    @property
+    def equipment(self) -> InMemoryTable:
+        return self.caches[0]
+
+    @property
+    def quality(self) -> InMemoryTable:
+        return self.caches[1]
+
+    def cache_rows(self) -> int:
+        return sum(c.n_rows for c in self.caches)
 
     # ----------------------------------------------------------- cache mgmt
     @property
@@ -238,23 +281,17 @@ class StreamProcessorWorker:
     def reset_caches(self, master_topics: Dict[str, str],
                      n_business_keys: int) -> float:
         """The paper's trigger: on (re)assignment, dump compacted snapshots
-        filtered by assigned business keys. Returns dump seconds (Fig. 4)."""
+        -- a partitioned table's filtered by assigned business keys, a
+        replicated table's whole. Returns dump seconds (Fig. 4)."""
         bkeys = self.assigned_business_keys(n_business_keys)
         total = 0.0
-        for cache, topic_name in (
-                (self.equipment, master_topics["equipment"]),
-                (self.quality, master_topics["quality"])):
-            topic = self.queue.topics[topic_name]
-            rks, pls, tts = topic.snapshot(bkeys)
-            # quality cache joins by prod_id (payload col 3); equipment by
-            # business key (payload col 1)
-            if cache is self.quality and len(rks):
-                join_keys = pls[:, 3].astype(np.int64)
-            elif len(rks):
-                join_keys = pls[:, 1].astype(np.int64)
-            else:
-                join_keys = rks
-            total += cache.reset_from_snapshot(join_keys, pls, tts)
+        for t, cache in self.masters:
+            topic = self.queue.topics[master_topics[t.name]]
+            rks, pls, tts = topic.snapshot(
+                bkeys if t.scope == "partitioned" else None)
+            total += cache.reset_from_snapshot(
+                pls[:, t.join_key].astype(np.int64), pls, tts)
+        self._arrived = None
         return total
 
     def migrate_caches(self, master_topics: Dict[str, str],
@@ -266,52 +303,101 @@ class StreamProcessorWorker:
         compacted master topics ONLY the keys gained since ``prev_bkeys``
         — so a survivor that merely gains (or loses) a slice of the key
         space keeps its cache warm instead of re-dumping the world (the
-        post-rebalance throughput crater PR 2 measured)."""
+        post-rebalance throughput crater a full re-dump causes).
+
+        A replicated table holds every key already and is left whole; a
+        joining worker's empty replicated cache gets the whole snapshot."""
         t0 = time.perf_counter()
         new_bkeys = self.assigned_business_keys(n_business_keys)
         gained = np.setdiff1d(new_bkeys, prev_bkeys)
         stats = CacheMigrationStats(prev_keys=len(prev_bkeys),
                                     new_keys=len(new_bkeys),
                                     gained_keys=len(gained))
-        for cache, topic_name in (
-                (self.equipment, master_topics["equipment"]),
-                (self.quality, master_topics["quality"])):
+        for t, cache in self.masters:
+            if t.scope != "partitioned":
+                if not cache.n_rows:
+                    rks, pls, tts = self.queue.topics[
+                        master_topics[t.name]].snapshot()
+                    cache.upsert(pls[:, t.join_key].astype(np.int64), pls,
+                                 tts)
+                    stats.gained_rows += len(rks)
+                continue
             kept, dropped = cache.retain_only(new_bkeys)
             stats.retained_rows += kept
             stats.dropped_rows += dropped
             if len(gained):
-                rks, pls, tts = self.queue.topics[topic_name].snapshot(gained)
+                rks, pls, tts = self.queue.topics[
+                    master_topics[t.name]].snapshot(gained)
                 if len(rks):
-                    if cache is self.quality:
-                        join_keys = pls[:, 3].astype(np.int64)
-                    else:
-                        join_keys = pls[:, 1].astype(np.int64)
-                    cache.upsert(join_keys, pls, tts)
+                    cache.upsert(pls[:, t.join_key].astype(np.int64), pls,
+                                 tts)
                     stats.gained_rows += len(rks)
         stats.dump_s = time.perf_counter() - t0
+        self._arrived = None
         return stats
 
     # ----------------------------------------------------- master ingestion
     def pump_master(self, topic: str, cache: InMemoryTable,
-                    max_records: Optional[int] = None) -> int:
+                    max_records: Optional[int] = None,
+                    past_horizon: bool = False) -> int:
         """In-memory Table Updater: consume ALL master partitions as one
-        coalesced batch, filter by assigned business keys (vectorized),
-        upsert into the local cache in one pass."""
+        coalesced batch, filter a partitioned table by assigned business
+        keys (vectorized), upsert into the local cache in one pass. With
+        masters applied in log order, only the changes up to the newest
+        operational record fetched (``horizon``) are consumed, and the
+        cache is then complete up to it (its watermark); ``past_horizon``
+        consumes every change logged so far."""
+        t = self._table_of_topic[topic]
+        until = self.horizon if self._log_order and not past_horizon else None
         batch, counts = self.queue.consume_many(
-            self.group, topic, self.partitions_for_master(topic), max_records)
+            self.group, topic, self.partitions_for_master(topic), max_records,
+            until_txn=until)
         for p, c in counts.items():
             self.queue.commit(self.group, topic, p, c)
+        if until is not None:
+            cache.watermark = max(cache.watermark, until)
         if not len(batch):
             return 0
-        mine = self._filter_assigned(batch)
+        mine = (self._filter_assigned(batch) if t.scope == "partitioned"
+                else batch)
         if not len(mine):
             return 0
-        if cache is self.quality:
-            join_keys = mine.payload[:, 3].astype(np.int64)
-        else:
-            join_keys = mine.payload[:, 1].astype(np.int64)
-        cache.upsert(join_keys, mine.payload, mine.txn_time)
+        before = getattr(cache, "overflows", 0)
+        cache.upsert(mine.payload[:, t.join_key].astype(np.int64),
+                     mine.payload, mine.txn_time)
+        if t.scope != "partitioned":
+            self._arrived = None
+        elif self._arrived is not None:
+            self._arrived.append(mine.business_key)
+        over = getattr(cache, "overflows", 0) - before
+        if over:
+            self._c_overflow.inc(over)
+            self.tracer.count("cache.asof_overflows", over)
         return len(mine)
+
+    def take_arrivals(self) -> Optional[np.ndarray]:
+        """The sorted business keys whose master rows arrived since the
+        last call (None: any held record may join now), for a retry sweep
+        to narrow itself to the records they can complete."""
+        got, self._arrived = self._arrived, []
+        if got is None:
+            return None
+        return (np.unique(np.concatenate(got)) if got
+                else np.zeros(0, np.int64))
+
+    def pump_masters(self, past_horizon: bool = False) -> int:
+        """``pump_master`` over every cached master table. ``past_horizon``
+        (masters in log order): records held in the late buffer leave no
+        room for a fetch, so no fetch can move the horizon to their master
+        rows; every change logged so far is consumed, and a pump that got
+        rows so is counted."""
+        got = sum(self.pump_master(self._master_topics[t.name], c,
+                                   past_horizon=past_horizon)
+                  for t, c in self.masters)
+        if got and past_horizon and self._log_order:
+            self._c_lifts.inc()
+            self.tracer.count("ingest.horizon_lifted", 1)
+        return got
 
     def partitions_for_master(self, topic: str) -> List[int]:
         # master topics are row-key partitioned: a worker's business keys can
@@ -326,8 +412,45 @@ class StreamProcessorWorker:
         WITHOUT committing (the concurrent runtime's ingest stage; commits
         happen after warehouse load in its load stage). Returns
         (batch, {partition: records_read})."""
-        return self.queue.fetch_many(self.group, topic, self.partitions,
-                                     max_records)
+        batch, counts = self.queue.fetch_many(self.group, topic,
+                                              self.partitions, max_records)
+        if self._log_order and len(batch):
+            self.horizon = max(self.horizon, int(batch.txn_time.max()))
+        return batch, counts
+
+    def hold_mask(self, batch: RecordBatch, found: np.ndarray) -> np.ndarray:
+        """The records of a transformed batch to hold in the late buffer:
+        those whose master rows were not found and, for a keyed target,
+        every record whose row key has an earlier record held (in the
+        buffer, or earlier in this batch), so that an update never
+        overtakes the insert it updates."""
+        held = ~found
+        if not self.keyed:
+            return held
+        keys = batch.row_key
+        held |= np.isin(keys, self.buffer.row_keys())
+        idx = np.flatnonzero(held)
+        if len(idx):
+            hk, first = np.unique(keys[idx], return_index=True)
+            pos = np.minimum(np.searchsorted(hk, keys), len(hk) - 1)
+            held |= (hk[pos] == keys) & (idx[first][pos]
+                                         < np.arange(len(keys)))
+        return held
+
+    def load_rows(self, rows: np.ndarray, event_times=None, rollup=None,
+                  routing_epoch=None) -> int:
+        """Target Database Updater: upsert into a keyed target, else append
+        the rows partitioned by their business key. Returns rows
+        loaded."""
+        if self.keyed:
+            with self.tracer.span("load.upsert") as sp:
+                updated = self.warehouse.upsert(rows)
+                sp.put("records", len(rows))
+                sp.put("updated", updated)
+            return len(rows)
+        return self.warehouse.load_partitioned(
+            rows, self.cfg.n_partitions, event_times=event_times,
+            rollup=rollup, routing_epoch=routing_epoch)
 
     def process_operational(self, topic: str, max_records: Optional[int] = None
                             ) -> int:
@@ -344,6 +467,9 @@ class StreamProcessorWorker:
                 self.group, topic, self.partitions, max_records)
             if not len(batch):
                 sp.drop()                # keep idle polls out of the trace
+        if self._log_order and len(batch):
+            self.horizon = max(self.horizon, int(batch.txn_time.max()))
+            self.pump_masters()
         self.fault.trip(INGEST_FETCH)
         buffered0 = self.buffer.total_buffered
         with self.tracer.span("transform.dispatch") as sp:
@@ -356,10 +482,16 @@ class StreamProcessorWorker:
         self.fault.trip(TRANSFORM_DONE)
         block.start_host_copy()          # D2H rides behind the compute
         with self.tracer.span("load.commit") as sp:
-            facts, _ = self.transformer.finish(block, merged)
-            done = self.warehouse.load_partitioned(
-                facts, self.cfg.n_partitions, rollup=block.rollup_host(),
-                routing_epoch=self.queue.topics[topic].routing.epoch)
+            rows, found = block.to_host()
+            held = self.hold_mask(merged, found)
+            self.buffer.push(merged.filter(held))
+            self.transformer.records_late += int(held.sum())
+            self.transformer.records_out += int((~held).sum())
+            done = 0
+            if not held.all():
+                done = self.load_rows(
+                    rows[~held], rollup=block.rollup_host(),
+                    routing_epoch=self.queue.topics[topic].routing.epoch)
             self.fault.trip(LOAD_PRE_COMMIT)
             # commit AFTER the warehouse load (crash-consistency: a death
             # between load and commit re-serves the records, but recovery
@@ -407,9 +539,13 @@ class DODETLPipeline:
         self.queue = MessageQueue(metrics=self.metrics)
         self.tracker = ChangeTracker(cfg, source.log, self.queue)
         self.warehouse = StarSchemaWarehouse(backend=self.backend)
+        if cfg.target_key is not None:
+            self.warehouse.keyed_target(cfg.target_key, cfg.target_keep)
         self.operational_topics = [self.tracker.topic_of(t.name)
                                    for t in cfg.operational_tables]
-        self.master_topic_map = self._master_topics()
+        # cached master table -> its topic, in the configuration's order
+        self.master_topic_map = {t.name: self.tracker.topic_of(t.name)
+                                 for t in cfg.cached_tables}
         # pluggable partitioning: operational topics share ONE routing
         # timeline produced by the configured strategy ("static" keeps the
         # exact legacy hash%n behavior at epoch 0)
@@ -433,16 +569,6 @@ class DODETLPipeline:
         w.attach_metrics(self.metrics.shard(name))
         return w
 
-    def _master_topics(self) -> Dict[str, str]:
-        """Logical master role -> topic. The simple schema has 'equipment'
-        and 'quality'; the ISA-95 complex schema maps its first two master
-        tables onto those roles (extra tables exercise join_depth)."""
-        masters = [t for t in self.cfg.tables if t.nature == "master"]
-        eq = next((t for t in masters if "equipment" in t.name), masters[0])
-        qu = next((t for t in masters if "quality" in t.name), masters[-1])
-        return {"equipment": self.tracker.topic_of(eq.name),
-                "quality": self.tracker.topic_of(qu.name)}
-
     def _apply_assignment(self):
         for w in self.workers:
             w.partitions = self.assignment.partitions_of(w.name)
@@ -451,21 +577,39 @@ class DODETLPipeline:
     def extract(self, limit_per_table: Optional[int] = None) -> int:
         return self.tracker.poll_all(limit_per_table)
 
-    def bootstrap_caches(self) -> float:
-        """Initial snapshot dump for every worker (Fig. 4 overhead)."""
-        total = 0.0
+    def bootstrap_caches(self, snapshot: Optional[Dict[str, tuple]] = None
+                         ) -> float:
+        """Initial snapshot dump for every worker (Fig. 4 overhead): from
+        the compacted master topics, or from ``snapshot`` -- per cached
+        table, the (business keys, payloads, txn times) of a dump taken
+        outside the broker (a master table's history too large to publish)
+        -- a partitioned table filtered by each worker's partitions under
+        the current routing, a replicated one whole."""
+        if snapshot is None:
+            return sum(w.reset_caches(self.master_topic_map,
+                                      self.cfg.n_business_keys)
+                       for w in self.workers)
+        t0 = time.perf_counter()
+        routing = self.current_routing()
+        for i, t in enumerate(self.cfg.cached_tables):
+            bks, pls, tts = snapshot[t.name]
+            parts = (routing.partition_of(np.asarray(bks, np.int64))
+                     if t.scope == "partitioned" else None)
+            for w in self.workers:
+                sel = (slice(None) if parts is None else
+                       np.isin(parts, np.asarray(w.partitions)))
+                w.caches[i].upsert(pls[sel, t.join_key].astype(np.int64),
+                                   pls[sel], tts[sel])
         for w in self.workers:
-            total += w.reset_caches(self.master_topic_map,
-                                    self.cfg.n_business_keys)
-        return total
+            w._arrived = None
+        return time.perf_counter() - t0
 
     def step(self, max_records_per_partition: Optional[int] = None) -> int:
         """One streaming micro-batch across all workers: pump master topics
         into caches, then transform operational partitions."""
         done = 0
         for w in self.workers:
-            w.pump_master(self.master_topic_map["equipment"], w.equipment)
-            w.pump_master(self.master_topic_map["quality"], w.quality)
+            w.pump_masters()
         for w in self.workers:
             for topic in self.operational_topics:
                 done += w.process_operational(topic,
@@ -563,8 +707,7 @@ class DODETLPipeline:
         load-aware sticky partition reassignment with exactly-once offset
         transfer → buffers re-homed. Returns migration stats."""
         self.retire_routing()
-        initial_rows = sum(w.equipment.n_rows + w.quality.n_rows
-                           for w in self.workers)
+        initial_rows = sum(w.cache_rows() for w in self.workers)
         part_loads, keys, counts = self.observed_loads()
         cur = self.current_routing()
         new_table = self.strategy.rebalanced_table(cur, part_loads,
@@ -703,7 +846,8 @@ class DODETLPipeline:
         for w in self.workers:
             if w.name in state["buffers"]:
                 w.buffer = OperationalMessageBuffer.restore(
-                    state["buffers"][w.name], self.cfg.buffer_capacity)
+                    state["buffers"][w.name], self.cfg.buffer_capacity,
+                    w.keyed)
                 w.transformer.buffer = w.buffer
         for l in self.tracker.listeners:
             if l.table.name in state["listener_offsets"]:

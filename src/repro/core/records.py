@@ -1,9 +1,13 @@
 """Record model shared by every DOD-ETL stage.
 
 A *record batch* is a struct-of-arrays (host numpy; device jnp inside the
-Stream Processor): integer identity/ordering fields plus a fixed-width f32
-payload — the TPU-native stand-in for a database row. Fixed widths keep
-every stage jit-compatible.
+Stream Processor): integer identity/ordering fields plus a fixed-width
+payload — the TPU-native stand-in for a database row. Width and lanes are
+the table's (``TableConfig.columns`` / ``lanes``): float32 for the
+steelworks tables (``PAYLOAD_WIDTH`` lanes), int32 where ids, date keys
+and cents pass 2**24. Fixed widths keep every stage jit-compatible. The
+shared change log holds each deployment's rows at its widest table's
+width; a Listener hands a narrower table's topic only its own lanes.
 """
 from __future__ import annotations
 
@@ -28,7 +32,7 @@ class RecordBatch:
     business_key: np.ndarray   # i64 [n]   domain partition key
     txn_time: np.ndarray       # i64 [n]   transaction timestamp (ns ticks)
     lsn: np.ndarray            # i64 [n]   log sequence number
-    payload: np.ndarray        # f32 [n, PAYLOAD_WIDTH]
+    payload: np.ndarray        # f32|i32 [n, table width]
 
     def __post_init__(self):
         n = len(self.row_key)
@@ -118,5 +122,11 @@ def make_batch(table_id: int, op: int, row_key, business_key, txn_time,
         business_key=np.asarray(business_key, np.int64),
         txn_time=np.asarray(txn_time, np.int64),
         lsn=np.arange(lsn_start, lsn_start + n, dtype=np.int64),
-        payload=np.asarray(payload, np.float32).reshape(n, -1),
+        payload=np.asarray(payload, payload_dtype(payload)).reshape(n, -1),
     )
+
+
+def payload_dtype(payload) -> type:
+    """int32 for integer lanes, float32 otherwise."""
+    return (np.int32 if np.issubdtype(np.asarray(payload).dtype, np.integer)
+            else np.float32)

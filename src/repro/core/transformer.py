@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.cache import InMemoryTable
-
 EPS = 1e-6
 
 FACT_COLUMNS = ("equipment_id", "t_start", "t_end", "availability",
@@ -142,43 +140,43 @@ class DataTransformer:
     """Stateful wrapper: caches + late buffer + metrics for one worker.
     The numeric core is delegated to the selected ``ComputeBackend`` —
     one fused transform dispatch per call, regardless of how many queue
-    partitions were coalesced into the batch. With ``n_units`` set the
-    dispatch also carries the per-unit KPI rollup (the fused
-    ``transform_and_rollup`` op), and the result stays device-resident
-    as a ``FactBlock`` until the warehouse-load boundary."""
+    partitions were coalesced into the batch — whose body the
+    configuration's ``schema`` picks. ``caches`` are the worker's master
+    caches in the schema's order (steelworks: equipment, quality). With
+    ``n_units`` set the steelworks dispatch also carries the per-unit KPI
+    rollup (the fused ``transform_and_rollup`` op), and the result stays
+    device-resident as a ``FactBlock`` until the warehouse-load
+    boundary."""
 
-    def __init__(self, equipment: InMemoryTable, quality: InMemoryTable,
-                 buffer, join_depth: int = 1, backend=None,
-                 n_units: Optional[int] = None):
+    def __init__(self, caches, buffer, join_depth: int = 1, backend=None,
+                 n_units: Optional[int] = None, schema: str = "steelworks"):
         from repro.core.backend import get_backend
-        self.equipment = equipment
-        self.quality = quality
+        self.caches = tuple(caches)
         self.buffer = buffer
         self.join_depth = join_depth
         self.n_units = n_units    # fused-rollup width (None: facts only)
+        self.schema = schema
         self.backend = get_backend(backend)
         self.records_out = 0
         self.records_late = 0
         self.dispatches = 0     # device dispatch count (the tentpole metric)
 
     def watermark(self) -> int:
-        return min(self.equipment.watermark, self.quality.watermark)
+        return min(c.watermark for c in self.caches)
 
-    def transform_block(self, batch, equipment=None, quality=None):
+    def transform_block(self, batch, *snapshots):
         """Pure numeric transform of a RecordBatch: ONE backend dispatch,
         no buffer interaction, NO host sync — returns a device-resident
-        ``FactBlock`` (facts + found + fused per-unit rollup when
-        ``n_units`` is configured). The concurrent runtime's transform
-        stage calls this with immutable ``CacheSnapshot`` views (taken
-        under the worker's cache lock) so the dispatch itself runs
-        LOCK-FREE and overlaps the ingest stage's master pumps; the block
-        materializes to host only in the load stage, under the worker's
-        commit lock, so device compute + D2H overlap the load stage's
-        host work instead of blocking here."""
+        ``FactBlock`` (rows + found, and for steelworks the fused per-unit
+        rollup when ``n_units`` is configured). The concurrent runtime's
+        transform stage calls this with immutable ``CacheSnapshot`` views
+        of every cache (taken under the worker's cache lock) so the
+        dispatch itself runs LOCK-FREE and overlaps the ingest stage's
+        master pumps; the block materializes to host only in the load
+        stage, under the worker's commit lock, so device compute + D2H
+        overlap the load stage's host work instead of blocking here."""
         block = self.backend.transform_block(
-            batch.payload,
-            equipment if equipment is not None else self.equipment,
-            quality if quality is not None else self.quality,
+            batch.payload, *(snapshots or self.caches), schema=self.schema,
             join_depth=self.join_depth, n_units=self.n_units)
         self.dispatches += 1
         return block

@@ -98,10 +98,8 @@ class RecoveryCoordinator:
                     w.name: {
                         "buffer": w.buffer.export_state(),
                         "dead_letter": w.dead_letter.export_state(),
-                        "watermarks": {
-                            "equipment": int(w.equipment.watermark),
-                            "quality": int(w.quality.watermark),
-                        },
+                        "watermarks": {t.name: int(c.watermark)
+                                       for t, c in w.masters},
                     } for w in pipe.workers},
                 "listeners": {l.table.name: int(l.offset)
                               for l in pipe.tracker.listeners},
@@ -176,14 +174,15 @@ class RecoveryCoordinator:
             ws = state["workers"].get(w.name)
             if ws is None:
                 continue
-            w.buffer = _restore_buffer(ws["buffer"], pipe.cfg.buffer_capacity)
+            w.buffer = _restore_buffer(ws["buffer"], pipe.cfg.buffer_capacity,
+                                       w.keyed)
             w.transformer.buffer = w.buffer
             # quarantined records' offsets are committed — losing the DLQ
             # across a restore would silently lose the records themselves
             w.dead_letter = _restore_dead_letter(ws.get("dead_letter"))
             w.reset_caches(pipe.master_topic_map, pipe.cfg.n_business_keys)
-            w.equipment.watermark = int(ws["watermarks"]["equipment"])
-            w.quality.watermark = int(ws["watermarks"]["quality"])
+            for t, c in w.masters:
+                c.watermark = int(ws["watermarks"][t.name])
         # 6. serving: resume the checkpointed epoch, replay only the
         #    chunk-log suffix past it
         replayed = 0
@@ -239,9 +238,10 @@ def recover_pipeline(cfg, source, journal: DurabilityJournal, *,
     return pipe, coord, info
 
 
-def _restore_buffer(state: Dict[str, Any], capacity: int):
+def _restore_buffer(state: Dict[str, Any], capacity: int,
+                    ordered: bool = False):
     from repro.core.buffer import OperationalMessageBuffer
-    return OperationalMessageBuffer.restore(state, capacity)
+    return OperationalMessageBuffer.restore(state, capacity, ordered)
 
 
 def _restore_dead_letter(state: Optional[Dict[str, Any]]):

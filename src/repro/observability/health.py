@@ -114,7 +114,7 @@ def build_cluster_health(cluster) -> Dict:
         w = rt.worker
         buffered = len(w.buffer)
         dead_lettered = len(w.dead_letter)
-        cache_rows = w.equipment.n_rows + w.quality.n_rows
+        cache_rows = w.cache_rows()
         total_dead_lettered += dead_lettered
         if not rt.dead:
             total_buffered += buffered
@@ -137,8 +137,7 @@ def build_cluster_health(cluster) -> Dict:
             "heartbeat_max_age_s": round(max(hb_ages), 4) if hb_ages
             else None,
             "cache_rows": cache_rows,
-            "cache": {"equipment": w.equipment.stats(),
-                      "quality": w.quality.stats()},
+            "cache": {t.name: c.stats() for t, c in w.masters},
             "freshness": rt.latency.percentiles(drain=False),
         }
 
@@ -228,7 +227,7 @@ def build_pipeline_health(pipe) -> Dict:
     total_cache_rows = 0
     for w in pipe.workers:
         buffered = len(w.buffer)
-        cache_rows = w.equipment.n_rows + w.quality.n_rows
+        cache_rows = w.cache_rows()
         total_buffered += buffered
         total_cache_rows += cache_rows
         workers[w.name] = {

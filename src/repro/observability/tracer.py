@@ -101,6 +101,10 @@ class _NullTracer:
                **args) -> None:
         return None
 
+    def count(self, name: str, value: int,
+              lane: Optional[str] = None) -> None:
+        return None
+
 
 NULL_TRACER = _NullTracer()
 
@@ -211,6 +215,14 @@ class StageTracer:
     def instant(self, name: str, lane: Optional[str] = None) -> None:
         self._record(name, lane or threading.current_thread().name,
                      self._clock(), None, None, ph="i")
+
+    def count(self, name: str, value: int,
+              lane: Optional[str] = None) -> None:
+        """A counter increment: an event of phase "C" whose ``value``
+        argument is the amount counted now (a metric sums the events that
+        fall in its window)."""
+        self._record(name, lane or threading.current_thread().name,
+                     self._clock(), None, {"value": value}, ph="C")
 
     def _record(self, name, lane, t_start, dur, args, ph="X") -> None:
         with self._lock:

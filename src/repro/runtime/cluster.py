@@ -85,8 +85,7 @@ class SimulatedCluster:
                   ) -> RoundStats:
         pipe = self.pipe
         for w in pipe.workers:
-            w.pump_master(pipe.master_topic_map["equipment"], w.equipment)
-            w.pump_master(pipe.master_topic_map["quality"], w.quality)
+            w.pump_masters()
         walls: Dict[str, float] = {}
         records = 0
         for w in pipe.workers:
@@ -219,9 +218,10 @@ class WorkerRuntime:
     transform, load) around a ``StreamProcessorWorker``, decoupled by
     bounded hand-off queues.
 
-      ingest    pumps master topics into the worker caches, then fetches
-                operational partitions (advancing broker READ positions,
-                committing nothing) and hands each coalesced batch off;
+      ingest    fetches operational partitions (advancing broker READ
+                positions, committing nothing), pumps master topics into
+                the worker caches (in log order: up to the newest record
+                fetched), then hands each coalesced batch off;
       transform one backend dispatch per run of queued same-topic hand-offs
                 (one per hand-off unless a backlog queued several; GIL
                 released in the numeric core, so transforms of different
@@ -250,6 +250,10 @@ class WorkerRuntime:
         self.control: "queue_mod.Queue[_Control]" = queue_mod.Queue()
         self.commit_lock = threading.Lock()
         self.cache_lock = threading.Lock()
+        # a versioned cache updates its device mirror in place (donated
+        # buffers), so a snapshot's arrays are valid only while the cache
+        # lock is held: the transform launches under it
+        self._pinned = any(c.asof_lane is not None for c in worker.caches)
         self.stop = threading.Event()
         self.dead = False
         self.fetched = 0             # hand-offs produced (ingest thread)
@@ -453,17 +457,8 @@ class WorkerRuntime:
         while not self.stop.is_set():
             self.beat("ingest")
             self._apply_control()
-            with self.tracer.span("ingest.pump") as sp:
-                with self.cache_lock:
-                    rows = (w.pump_master(pipe.master_topic_map["equipment"],
-                                          w.equipment)
-                            + w.pump_master(pipe.master_topic_map["quality"],
-                                            w.quality))
-                if rows:
-                    sp.put("rows", rows)
-                else:
-                    sp.drop()        # keep idle pumps out of traces
-            got = 0
+            works: List[_Work] = []
+            blocked = False
             for topic in pipe.operational_topics:
                 if self.stop.is_set():
                     break
@@ -480,6 +475,7 @@ class WorkerRuntime:
                 nparts = max(1, len(w.partitions))
                 cap = self._buffer_headroom() // nparts
                 if cap < 1:
+                    blocked = True
                     break            # let retries drain the buffer first
                 if self.cap is not None:
                     cap = min(cap, self.cap)
@@ -498,16 +494,29 @@ class WorkerRuntime:
                 if counts:
                     self.records_fetched += len(batch)
                     pipe.fault.trip(INGEST_FETCH)   # fetched, uncommitted
-                    seq = self.fetched
+                    works.append(_Work(topic, batch, counts, self.fetched,
+                                       0.0))
                     self.fetched += 1
-                    if not self._put(self.transform_q,
-                                     _Work(topic, batch, counts, seq,
-                                           time.perf_counter())):
-                        self.items_dropped_ingest += 1   # shutdown only
-                        self.records_dropped_ingest += len(batch)
-                        self.credits.refund(len(batch))
-                    got += len(batch)
-            if not got:
+            # masters after the fetch: a fetched record meets every master
+            # change logged before it (in log order, up to the newest one).
+            # Where held records alone fill the late buffer, only master
+            # rows can free them and no fetch can come to move the horizon
+            stuck = blocked and (pipe.cfg.buffer_capacity - len(w.buffer)
+                                 < max(1, len(w.partitions)))
+            with self.tracer.span("ingest.pump") as sp:
+                with self.cache_lock:
+                    rows = w.pump_masters(past_horizon=stuck)
+                if rows:
+                    sp.put("rows", rows)
+                else:
+                    sp.drop()        # keep idle pumps out of traces
+            for work in works:
+                work.t_ready = time.perf_counter()
+                if not self._put(self.transform_q, work):
+                    self.items_dropped_ingest += 1   # shutdown only
+                    self.records_dropped_ingest += len(work.batch)
+                    self.credits.refund(len(work.batch))
+            if not works:
                 time.sleep(pipe.cfg.idle_backoff_s)
 
     # -------------------------------------------------------- stage: transform
@@ -571,15 +580,22 @@ class WorkerRuntime:
             # queueing behind every dispatch
             with tr.span("transform.dispatch") as sp:
                 with tr.span("transform.snapshot") as ss:
-                    with self.cache_lock:
-                        eq = w.equipment.snapshot_view(device)
-                        qu = w.quality.snapshot_view(device)
-                        up = w.equipment.upload_bytes + w.quality.upload_bytes
+                    self.cache_lock.acquire()
+                    try:
+                        snaps = self._snapshots(device)
+                        up = sum(c.upload_bytes for c in w.caches)
+                    finally:
+                        if not self._pinned:
+                            self.cache_lock.release()
                     _tag(ss, n, seq)
                     ss.put("upload_bytes", up)
                 with tr.span("transform.launch") as sl:
-                    good, block, dead = self._transform_quarantine(
-                        batch, eq, qu)
+                    try:
+                        good, block, dead = self._transform_quarantine(
+                            batch, snaps)
+                    finally:
+                        if self._pinned:
+                            self.cache_lock.release()
                     _tag(sl, n, seq)
                 _tag(sp, n, seq)
                 sp.put("handoffs", len(run))
@@ -592,7 +608,11 @@ class WorkerRuntime:
                 self.records_dropped_transform += n
                 self.credits.refund(n)
 
-    def _transform_quarantine(self, batch: RecordBatch, eq, qu):
+    def _snapshots(self, device: bool) -> tuple:
+        """Cache-lock-held: an immutable view of every cache."""
+        return tuple(c.snapshot_view(device) for c in self.worker.caches)
+
+    def _transform_quarantine(self, batch: RecordBatch, snaps):
         """ONE fused transform+rollup dispatch, NO host sync: the block
         is handed to the load stage device-resident, with the D2H copy
         enqueued asynchronously behind the compute.
@@ -610,7 +630,7 @@ class WorkerRuntime:
         ``(good_batch, block_or_None, dead_batch_or_None)``."""
         tf = self.worker.transformer
         try:
-            return batch, tf.transform_block(batch, eq, qu
+            return batch, tf.transform_block(batch, *snaps
                                              ).start_host_copy(), None
         except (InjectedCrash, *DEVICE_ERRORS):
             raise
@@ -622,7 +642,7 @@ class WorkerRuntime:
         while stack:
             idx = stack.pop()
             try:
-                tf.transform_block(batch.take(idx), eq, qu)   # probe
+                tf.transform_block(batch.take(idx), *snaps)   # probe
                 good_idx.append(idx)
             except (InjectedCrash, *DEVICE_ERRORS):
                 raise
@@ -639,7 +659,7 @@ class WorkerRuntime:
                 else np.zeros(0, np.int64))
         good = batch.take(gsel)
         dead = batch.take(dsel)
-        block = (tf.transform_block(good, eq, qu).start_host_copy()
+        block = (tf.transform_block(good, *snaps).start_host_copy()
                  if len(good) else None)
         return good, block, (dead if len(dead) else None)
 
@@ -647,32 +667,37 @@ class WorkerRuntime:
     def _load_and_record(self, batch: RecordBatch, block, seq: int) -> int:
         """Commit-lock-held helper: materialize the device block (the
         step's ONE host↔device round trip — the async copy started at
-        dispatch time has usually landed by now), buffer lates, load
-        facts + fused rollup, sample freshness. Returns records loaded.
-        ``seq`` is the fetch ordinal the spans carry."""
+        dispatch time has usually landed by now), buffer lates (and, for a
+        keyed target, the records behind a held one of their key), load
+        rows + fused rollup, sample freshness. Returns records loaded.
+        ``seq`` is the fetch ordinal the spans carry (``RETRY_BATCH`` on
+        the retry path, whose records were counted when first held)."""
         w = self.worker
         with self.tracer.span("load.to_host") as sp:
             facts, found = block.to_host()
             rollup = block.rollup_host()
             _tag(sp, int(np.count_nonzero(found)), seq)
-        w.buffer.push(batch.filter(~found))
-        good = facts[found]
+        held = w.hold_mask(batch, found)
+        # a retry's records were just tried against the newest caches
+        w.buffer.push(batch.filter(held), fresh=seq != RETRY_BATCH)
+        good = facts[~held]
         # join-level cache accounting (same counters the sequential worker
         # feeds): hits joined now, misses went to the late buffer. Counted
         # from the already-materialized host mask — no extra device sync.
         w._c_hits.inc(len(good))
         w._c_misses.inc(len(batch) - len(good))
+        if seq != RETRY_BATCH and len(good) < len(batch):
+            w._c_held.inc(len(batch) - len(good))
+            self.tracer.count("load.held", len(batch) - len(good))
         if not len(good):
             return 0
         log = self.pipe.source.log
-        ev = log.event_times(batch.lsn[found])
+        ev = log.event_times(batch.lsn[~held])
         # event times ride into the warehouse so an attached serving layer
         # can stamp per-record report staleness on the same CDC clock
         with self.tracer.span("load.warehouse") as sp:
-            w.warehouse.load_partitioned(
-                good, self.pipe.cfg.n_partitions, event_times=ev,
-                rollup=rollup,
-                routing_epoch=self.pipe.current_routing().epoch)
+            w.load_rows(good, event_times=ev, rollup=rollup,
+                        routing_epoch=self.pipe.current_routing().epoch)
             _tag(sp, len(good), seq)
         self.latency.add(log.clock() - ev)
         self.records_done += len(good)
@@ -683,26 +708,38 @@ class WorkerRuntime:
         with self.commit_lock:
             if self.dead or not len(w.buffer):
                 return
+            # retry only what can join now: records held since the last
+            # sweep, and those whose business keys got master rows
+            with self.cache_lock:
+                keys = w.take_arrivals()
             # publish the pop to the ingest stage's headroom accounting
             # BEFORE shrinking the buffer, so a concurrent fetch can't
             # claim the slots these records still occupy logically
             self.retry_inflight = len(w.buffer)
             limit = (self.cap * max(1, len(w.partitions))
                      if self.cap else None)
-            ready = w.buffer.pop_ready(w.transformer.watermark(), limit)
+            ready = w.buffer.pop_ready(w.transformer.watermark(), limit,
+                                       keys)
             if len(ready):
                 with self.tracer.span("load.retry") as sp:
-                    device = w.backend.device
                     with self.cache_lock:
-                        eq = w.equipment.snapshot_view(device)
-                        qu = w.quality.snapshot_view(device)
-                    # the retry path meets poison records too (a poison
-                    # record that was merely *late* first) — same quarantine
-                    good, block, dead = self._transform_quarantine(
-                        ready, eq, qu)
+                        snaps = self._snapshots(w.backend.device)
+                        # the retry path meets poison records too (a
+                        # poison record that was merely *late* first) —
+                        # same quarantine; launched under the lock where
+                        # cache updates are in place (``_pinned``)
+                        if self._pinned:
+                            launched = self._transform_quarantine(ready,
+                                                                  snaps)
+                    if not self._pinned:
+                        launched = self._transform_quarantine(ready, snaps)
+                    good, block, dead = launched
                     if dead is not None:
                         w.dead_letter.push(dead, reason="transform-poison")
                         w._c_dead.inc(len(dead))
+                        # what a quarantined record held back may go now
+                        with self.cache_lock:
+                            w._arrived = None
                     if block is not None:
                         self._load_and_record(good, block, RETRY_BATCH)
                     _tag(sp, len(ready), RETRY_BATCH)
@@ -1339,7 +1376,7 @@ class ConcurrentCluster:
     def _initial_cache_rows(self) -> int:
         """Pre-migration cache rows across live workers — the retention
         baseline (see ``pipeline.migration_summary``)."""
-        return sum(rt.worker.equipment.n_rows + rt.worker.quality.n_rows
+        return sum(rt.worker.cache_rows()
                    for rt in self.runtimes.values() if not rt.dead)
 
     def _reroute_all(self, new_table):

@@ -120,6 +120,24 @@ def test_transform_rollup_compiles(one_chip):
                                   n_units=smoke.N_UNITS).compile()
 
 
+@pytest.mark.parametrize("block", [128, 1024])
+def test_dimtrade_transform_compiles(one_chip, block):
+    """The DimTrade kernel at the tpcdi.trade-backlog cell's DimAccount
+    slots per worker (2**25, 8 int32 lanes) and DimSecurity slots."""
+    from repro.core.dimtrade import ACCOUNT_COLUMNS, dimtrade_transform_kernel
+
+    def table(slots):
+        return (one_chip((slots,), jnp.int32),
+                one_chip((slots, 8), jnp.int32),
+                one_chip((slots,), jnp.int32))
+    compiled = dimtrade_transform_kernel.lower(
+        one_chip((block, 16), jnp.int32), *table(1 << 25), *table(1 << 18),
+        asof_lane=ACCOUNT_COLUMNS.index("effective_date")).compile()
+    mem = compiled.memory_analysis()
+    if mem is not None:
+        assert mem.temp_size_in_bytes < (1 << 30)
+
+
 @pytest.mark.parametrize("view", sorted(VIEWS))
 def test_fold_tree_and_gather_compile(one_chip, view):
     n_segments, lanes = VIEWS[view]
