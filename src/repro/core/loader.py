@@ -21,14 +21,16 @@ equipment dimension; loads are per-partition appends (each partition
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.partitioning import partition_bounds
+from repro.core.partitioning import partition_bounds, partition_of
 from repro.core.transformer import FACT_COLUMNS
+from repro.observability.tracer import NULL_TRACER
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,15 +168,104 @@ class KeyedTable:
                     np.arange(self.n))
 
 
+class KeyedShards:
+    """A keyed target split into one ``KeyedTable`` per owner (the workers
+    present when the target is made), each behind its own lock, so the
+    load stages' upserts do not queue on one lock. A fixed map sends each
+    static partition (``partition_of`` of the business key, the stable
+    hash ``load_partitioned`` uses, never the adaptive routing table) to
+    the shard of the worker that owned it when the target was made. Every
+    record of a key carries one business key, so a key's row lives in
+    exactly one shard for the table's life. A rebalance moves no row: a
+    moved partition stays in its shard, and its new owner takes that
+    shard's lock for those rows (they count as ``foreign``)."""
+
+    def __init__(self, key_col: int, keep: Tuple[int, ...],
+                 owners: Sequence[str], shard_of_partition: Sequence[int]):
+        self.key_col = key_col
+        self.keep = tuple(keep)
+        self.owners = tuple(owners)
+        self.shard_of_partition = np.asarray(shard_of_partition, np.int64)
+        self.shards = [KeyedTable(key_col, keep) for _ in self.owners]
+        self.locks = [threading.Lock() for _ in self.owners]
+
+    def upsert(self, block: np.ndarray,
+               tracer=NULL_TRACER) -> Tuple[int, Dict[str, int]]:
+        """Apply one block: target rows with each record's business key
+        as a last column, so the key that places a row travels with it
+        through any slice of the block. The rows are grouped by shard, and
+        each group goes in ONE ``KeyedTable.upsert`` under its shard's
+        lock, in block order (a key's records stay in log order). Returns
+        (keys inserted, {shard owner: rows applied in its shard})."""
+        block = np.asarray(block)
+        if not len(block):
+            return 0, {}
+        rows = block[:, :-1]
+        shard = self.shard_of_partition[partition_of(
+            block[:, -1].astype(np.int64), len(self.shard_of_partition))]
+        if (shard == shard[0]).all():      # a stage's own rows: no sort
+            groups = [(int(shard[0]), rows)]
+        else:
+            order = np.argsort(shard, kind="stable")
+            ks, first = np.unique(shard[order], return_index=True)
+            groups = [(int(k), rows[idx]) for k, idx in
+                      zip(ks, np.split(order, first[1:]))]
+        inserted = 0
+        for k, part in groups:
+            with tracer.span("load.upsert_lock") as sp:
+                self.locks[k].acquire()
+                sp.put("records", len(part))
+            try:
+                inserted += self.shards[k].upsert(part)
+            finally:
+                self.locks[k].release()
+        return inserted, {self.owners[k]: len(part) for k, part in groups}
+
+    def _tables(self) -> List[Tuple[np.ndarray, np.ndarray]]:
+        """Every shard's ``table()``, taken under all the shard locks at
+        once (in shard order; an upsert holds one at a time)."""
+        with contextlib.ExitStack() as stack:
+            for lk in self.locks:
+                stack.enter_context(lk)
+            return [t.table() for t in self.shards]
+
+    def table(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(rows, applied counts) of the whole target: the shards
+        concatenated in shard order, each in insertion order (copies)."""
+        parts = [p for p in self._tables() if len(p[0])] or [
+            self.shards[0].table()]
+        return (np.concatenate([r for r, _ in parts]),
+                np.concatenate([a for _, a in parts]))
+
+    def export(self) -> Dict:
+        return {"owners": list(self.owners),
+                "shard_of_partition": self.shard_of_partition.copy(),
+                "shards": [{"rows": r, "applied": a}
+                           for r, a in self._tables()]}
+
+    @classmethod
+    def restored(cls, key_col: int, keep: Tuple[int, ...],
+                 state: Dict) -> "KeyedShards":
+        """The shards and map of ``export()``'s output, whatever workers
+        the restoring pipeline has (``table()`` reads as it did)."""
+        out = cls(key_col, keep, state["owners"],
+                  state["shard_of_partition"])
+        for t, sh in zip(out.shards, state["shards"]):
+            t.restore(sh["rows"], sh["applied"])
+        return out
+
+
 class StarSchemaWarehouse:
     """Loads are thread-safe: the concurrent runtime's load stages append
     from one thread per worker, so the chunk log, commit sequence and
     delta publication are guarded by a single lock (the numpy split work
-    stays outside it)."""
+    stays outside it). A keyed target takes its shards' locks instead, and
+    bumps the load counters under ``_count_lock`` alone."""
 
-    def __init__(self, backend=None):
+    def __init__(self, backend=None, tracer=NULL_TRACER):
         self._chunk_log: List[np.ndarray] = []   # committed blocks, in order
         self._lock = threading.Lock()
+        self._count_lock = threading.Lock()
         self._serving = None                 # MaterializedViewEngine (opt.)
         self._shards = None                  # ShardOwnership (opt.)
         # per-shard chunk sub-logs, chunk-aligned: _shard_logs[k][i] holds
@@ -183,6 +274,7 @@ class StarSchemaWarehouse:
         # never moves rows across shards)
         self._shard_logs: List[List[np.ndarray]] = []
         self.backend = backend       # pipeline's ComputeBackend (or None)
+        self.tracer = tracer         # a keyed target's lock waits
         self.rows_loaded = 0
         self.load_calls = 0
         self.commit_seq = 0
@@ -192,32 +284,38 @@ class StarSchemaWarehouse:
         # per-partition loops, the record-at-a-time baseline — gap it
         self._kpi_running: Optional[np.ndarray] = None
         self._kpi_gap_rows = 0
-        self.keyed: Optional[KeyedTable] = None   # see ``keyed_target``
+        self.keyed: Optional[KeyedShards] = None   # see ``keyed_target``
 
-    def keyed_target(self, key_col: int,
-                     keep: Tuple[int, ...] = ()) -> KeyedTable:
-        """Make the warehouse's target a ``KeyedTable`` that ``upsert``
+    def keyed_target(self, key_col: int, keep: Tuple[int, ...],
+                     owners: Sequence[str],
+                     owner_of: Sequence[str]) -> KeyedShards:
+        """Make the warehouse's target a keyed table that ``upsert``
         updates in place (a dimension), in place of the appended fact
-        chunk log."""
+        chunk log: one shard per name in ``owners``, static partition
+        ``p`` (of ``len(owner_of)``) in the shard of ``owner_of[p]``."""
         with self._lock:
-            self.keyed = KeyedTable(key_col, keep)
+            self.keyed = KeyedShards(
+                key_col, keep, owners,
+                [list(owners).index(o) for o in owner_of])
             return self.keyed
 
-    def upsert(self, rows: np.ndarray) -> int:
-        """Apply one block of target rows to the keyed table, in order,
-        under the load lock (so concurrent workers' blocks never
-        interleave). Counts every row as loaded. Returns how many rows
-        updated a key already present. No view engine reads a keyed
-        target: folding updates would need retraction. The chunk log
+    def upsert(self, block: np.ndarray) -> Tuple[int, Dict[str, int]]:
+        """Apply one block (target rows, each with its record's business
+        key as a last column) to the keyed target, in order per key, under
+        the locks of the shards its keys map to (``KeyedShards.upsert``).
+        Counts every row as loaded and every shard call as a load call.
+        Returns (rows that updated a key already present, {shard owner:
+        rows applied in its shard}). No view engine reads a keyed target:
+        folding updates would need retraction. The chunk log
         (``commit_seq``) does not hold a keyed target; ``export_state``
         captures it whole."""
         if self._serving is not None:
             raise ValueError("a keyed target has no serving views")
-        with self._lock:
-            inserted = self.keyed.upsert(rows)
-            self.rows_loaded += len(rows)
-            self.load_calls += 1
-        return len(rows) - inserted
+        inserted, placed = self.keyed.upsert(block, self.tracer)
+        with self._count_lock:
+            self.rows_loaded += len(block)
+            self.load_calls += len(placed)
+        return len(block) - inserted, placed
 
     # ------------------------------------------------------------ serving hook
     def attach_serving(self, engine, replay_from: int = 0):
@@ -318,7 +416,7 @@ class StarSchemaWarehouse:
         """Journal capture at a commit boundary: the chunk-log SUFFIX
         past ``from_seq`` (``commit_seq == len(_chunk_log)`` — one
         committed chunk per commit) plus the full counter state."""
-        with self._lock:
+        with self._lock, self._count_lock:
             return {
                 "chunks": list(self._chunk_log[from_seq:]),
                 "seq": int(self.commit_seq),
@@ -327,9 +425,10 @@ class StarSchemaWarehouse:
                 "kpi_running": (None if self._kpi_running is None
                                 else self._kpi_running.copy()),
                 "kpi_gap_rows": int(self._kpi_gap_rows),
-                # a keyed target is captured whole at every step
-                "keyed": (None if self.keyed is None else
-                          dict(zip(("rows", "applied"), self.keyed.table()))),
+                # a keyed target is captured whole at every step: its
+                # shards and their partition map
+                "keyed": (None if self.keyed is None
+                          else self.keyed.export()),
             }
 
     def restore_state(self, state: Dict) -> None:
@@ -353,8 +452,8 @@ class StarSchemaWarehouse:
                                  else np.asarray(state["kpi_running"]))
             self._kpi_gap_rows = int(state["kpi_gap_rows"])
             if state.get("keyed") is not None:
-                self.keyed.restore(state["keyed"]["rows"],
-                                   state["keyed"]["applied"])
+                self.keyed = KeyedShards.restored(
+                    self.keyed.key_col, self.keyed.keep, state["keyed"])
 
     def _commit(self, block: np.ndarray,
                 event_times: Optional[np.ndarray],

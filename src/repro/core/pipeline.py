@@ -437,16 +437,20 @@ class StreamProcessorWorker:
                                          < np.arange(len(keys)))
         return held
 
-    def load_rows(self, rows: np.ndarray, event_times=None, rollup=None,
-                  routing_epoch=None) -> int:
-        """Target Database Updater: upsert into a keyed target, else append
-        the rows partitioned by their business key. Returns rows
-        loaded."""
+    def load_rows(self, rows: np.ndarray, business_keys: np.ndarray,
+                  event_times=None, rollup=None, routing_epoch=None) -> int:
+        """Target Database Updater: upsert into a keyed target (the
+        records' ``business_keys`` pick its shards), else append the rows
+        partitioned by their business key. Returns rows loaded."""
         if self.keyed:
             with self.tracer.span("load.upsert") as sp:
-                updated = self.warehouse.upsert(rows)
+                updated, placed = self.warehouse.upsert(
+                    np.column_stack([rows, business_keys]))
                 sp.put("records", len(rows))
                 sp.put("updated", updated)
+            foreign = len(rows) - placed.get(self.name, 0)
+            if foreign:
+                self.tracer.count("load.upsert_foreign", foreign)
             return len(rows)
         return self.warehouse.load_partitioned(
             rows, self.cfg.n_partitions, event_times=event_times,
@@ -490,7 +494,8 @@ class StreamProcessorWorker:
             done = 0
             if not held.all():
                 done = self.load_rows(
-                    rows[~held], rollup=block.rollup_host(),
+                    rows[~held], merged.business_key[~held],
+                    rollup=block.rollup_host(),
                     routing_epoch=self.queue.topics[topic].routing.epoch)
             self.fault.trip(LOAD_PRE_COMMIT)
             # commit AFTER the warehouse load (crash-consistency: a death
@@ -538,9 +543,8 @@ class DODETLPipeline:
             "routing_epoch", lambda: self.current_routing().epoch)
         self.queue = MessageQueue(metrics=self.metrics)
         self.tracker = ChangeTracker(cfg, source.log, self.queue)
-        self.warehouse = StarSchemaWarehouse(backend=self.backend)
-        if cfg.target_key is not None:
-            self.warehouse.keyed_target(cfg.target_key, cfg.target_keep)
+        self.warehouse = StarSchemaWarehouse(backend=self.backend,
+                                             tracer=self.tracer)
         self.operational_topics = [self.tracker.topic_of(t.name)
                                    for t in cfg.operational_tables]
         # cached master table -> its topic, in the configuration's order
@@ -558,6 +562,13 @@ class DODETLPipeline:
         self.assignment = PartitionAssignment(
             cfg.n_partitions, [w.name for w in self.workers])
         self._apply_assignment()
+        if cfg.target_key is not None:
+            # one shard per worker, each static partition in its owner's
+            self.warehouse.keyed_target(
+                cfg.target_key, cfg.target_keep,
+                [w.name for w in self.workers],
+                [self.assignment.assignment[p]
+                 for p in range(cfg.n_partitions)])
 
     def _new_worker(self, name: str,
                     join_depth: int = 1) -> StreamProcessorWorker:

@@ -696,7 +696,8 @@ class WorkerRuntime:
         # event times ride into the warehouse so an attached serving layer
         # can stamp per-record report staleness on the same CDC clock
         with self.tracer.span("load.warehouse") as sp:
-            w.load_rows(good, event_times=ev, rollup=rollup,
+            w.load_rows(good, batch.business_key[~held], event_times=ev,
+                        rollup=rollup,
                         routing_epoch=self.pipe.current_routing().epoch)
             _tag(sp, len(good), seq)
         self.latency.add(log.clock() - ev)
